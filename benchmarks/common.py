@@ -37,15 +37,20 @@ def smoke_out_path(name: str, smoke: bool, out: str | None) -> str | None:
     return None
 
 
-def run_with_devices(module: str, num_devices: int, timeout: int = 1200, smoke: bool = False) -> str:
-    """Run ``python -m <module>`` in a subprocess with N forced host devices
-    (the device count is locked at jax init, so multi-device benchmarks need
-    their own process)."""
+def run_with_devices(
+    module: str, num_devices: int | None, timeout: int = 1200, smoke: bool = False,
+) -> str:
+    """Run ``python -m <module>`` in a subprocess, with N forced host devices
+    unless ``num_devices`` is None (the device count is locked at jax init,
+    so multi-device benchmarks need their own process). The caller must not
+    have touched jax: a parent that holds the chip leaves none for the child.
+    """
     import subprocess
     import sys
 
     env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={num_devices}"
+    if num_devices is not None:
+        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={num_devices}"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          os.path.join(os.path.dirname(__file__), ".."),

@@ -85,6 +85,8 @@ def run(smoke: bool = False) -> dict:
 
 
 if __name__ == "__main__":
-    r = run()
+    import sys
+
+    r = run(smoke="--smoke" in sys.argv)
     for k, v in r["results"].items():
         print(k, v)

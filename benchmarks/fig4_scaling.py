@@ -90,7 +90,11 @@ def _ring_bytes_per_sweep(coo, K: int, S: int) -> dict:
 
 def _run_layout(procs: int, dev_per_proc: int, spec: SyntheticSpec, K: int,
                 sweeps: int, timeout: float) -> dict:
-    """One launcher run at ``procs x dev_per_proc``; parse sweeps/s."""
+    """One launcher run at ``procs x dev_per_proc``; parse sweeps/s.
+
+    The launcher's children run on the host CPU (``JAX_PLATFORMS=cpu``), so
+    none of them needs the device this process may hold.
+    """
     cmd = [
         sys.executable, os.path.join(REPO_ROOT, "scripts", "launch_multiproc.py"),
         "--num-processes", str(procs), "--devices-per-process", str(dev_per_proc),

@@ -1,8 +1,8 @@
 """§Roofline aggregator: experiments/dryrun JSONs -> the per-cell table.
 
-    python -m benchmarks.roofline [--mesh pod16x16] [--markdown]
+    python -m benchmarks.roofline [--mesh pod16x16] [--smoke | --out PATH]
 
-Prints (and saves) per (arch x shape): the three roofline terms in seconds,
+Prints (and saves; --smoke to a temp path) per (arch x shape): the three roofline terms in seconds,
 the dominant term, MODEL_FLOPS/HLO_FLOPS, HBM fit, and the roofline
 fraction. No jax needed — pure JSON aggregation.
 """
@@ -13,7 +13,7 @@ import glob
 import json
 import os
 
-from benchmarks.common import save_result
+from benchmarks.common import save_result, smoke_out_path
 
 DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments", "dryrun")
 
@@ -60,10 +60,16 @@ def table(mesh: str = "pod16x16") -> tuple[list[dict], str]:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", default="pod16x16")
+    ap.add_argument("--smoke", action="store_true",
+                    help="write to a temp path, never experiments/bench")
+    ap.add_argument("--out", help="write the table here instead")
     args = ap.parse_args(argv)
     rows, md = table(args.mesh)
     print(md)
-    save_result(f"roofline_{args.mesh}", {"rows": rows, "markdown": md})
+    name = f"roofline_{args.mesh}"
+    path = save_result(name, {"rows": rows, "markdown": md},
+                       out=smoke_out_path(name, args.smoke, args.out))
+    print(f"wrote {path}")
     return 0
 
 

@@ -3,9 +3,11 @@
     PYTHONPATH=src python -m benchmarks.run [--full]
 
 Default is smoke scale (CI-sized, minutes); --full runs the paper-scale
-variants. Multi-device benchmarks (fig4/fig5/rmse) run in subprocesses with
-forced host device counts. The roofline table aggregates whatever dry-run
-artifacts exist under experiments/dryrun.
+variants. Every benchmark runs in its own subprocess and this process never
+imports jax, so a child can claim the one device of a TPU host; the
+multi-device ones (fig4/fig5/rmse) get forced host device counts. The
+roofline table aggregates whatever dry-run artifacts exist under
+experiments/dryrun.
 """
 from __future__ import annotations
 
@@ -16,89 +18,44 @@ import traceback
 
 from benchmarks.common import run_with_devices
 
+# name -> (title, module, forced host devices or None, output tail)
+SECTIONS = {
+    "fig2": ("fig2: per-item update cost vs nnz", "benchmarks.fig2_item_update",
+             None, 1200),
+    "fig3": ("fig3: single-node updates/s (bucketing variants)", "benchmarks.fig3_multicore",
+             None, 1200),
+    "fig4": ("fig4: distributed strong scaling (8 host devices)", "benchmarks.fig4_scaling",
+             8, 1200),
+    "fig5": ("fig5: compute/comm overlap (ring vs allgather)", "benchmarks.fig5_overlap",
+             8, 800),
+    "rmse": ("rmse: accuracy parity across all versions", "benchmarks.rmse_convergence",
+             4, 800),
+    "roofline": ("roofline: dry-run aggregation", "benchmarks.roofline",
+                 None, 800),
+}
+
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true", help="paper-scale (slow)")
-    ap.add_argument("--only", help="comma list: fig2,fig3,fig4,fig5,rmse,roofline")
+    ap.add_argument("--only", help="comma list: " + ",".join(SECTIONS))
     args = ap.parse_args(argv)
     smoke = not args.full
     only = set(args.only.split(",")) if args.only else None
 
     failures = []
-
-    def section(name: str):
-        print(f"\n=== {name} {'(smoke)' if smoke else '(full)'} ===", flush=True)
-        return time.time()
-
-    def done(name: str, t0: float):
+    for name, (title, module, devices, tail) in SECTIONS.items():
+        if only is not None and name not in only:
+            continue
+        print(f"\n=== {title} {'(smoke)' if smoke else '(full)'} ===", flush=True)
+        t0 = time.time()
+        try:
+            out = run_with_devices(module, devices, smoke=smoke)
+            print(out[-tail:])
+        except Exception:
+            failures.append(name)
+            traceback.print_exc()
         print(f"=== {name} done in {time.time()-t0:.1f}s ===", flush=True)
-
-    if only is None or "fig2" in only:
-        t0 = section("fig2: per-item update cost vs nnz")
-        try:
-            from benchmarks import fig2_item_update
-
-            r = fig2_item_update.run(smoke=smoke)
-            print("cost model:", r["cost_model"])
-        except Exception:
-            failures.append("fig2")
-            traceback.print_exc()
-        done("fig2", t0)
-
-    if only is None or "fig3" in only:
-        t0 = section("fig3: single-node updates/s (bucketing variants)")
-        try:
-            from benchmarks import fig3_multicore
-
-            r = fig3_multicore.run(smoke=smoke)
-            print("bucketed-vs-maxpad speedup: "
-                  f"{r['results']['speedup_bucketed_vs_maxpad']:.2f}x")
-        except Exception:
-            failures.append("fig3")
-            traceback.print_exc()
-        done("fig3", t0)
-
-    if only is None or "fig4" in only:
-        t0 = section("fig4: distributed strong scaling (8 host devices)")
-        try:
-            print(run_with_devices("benchmarks.fig4_scaling", 8, smoke=smoke)[-1200:])
-        except Exception:
-            failures.append("fig4")
-            traceback.print_exc()
-        done("fig4", t0)
-
-    if only is None or "fig5" in only:
-        t0 = section("fig5: compute/comm overlap (ring vs allgather)")
-        try:
-            print(run_with_devices("benchmarks.fig5_overlap", 8, smoke=smoke)[-800:])
-        except Exception:
-            failures.append("fig5")
-            traceback.print_exc()
-        done("fig5", t0)
-
-    if only is None or "rmse" in only:
-        t0 = section("rmse: accuracy parity across all versions")
-        try:
-            print(run_with_devices("benchmarks.rmse_convergence", 4, smoke=smoke)[-800:])
-        except Exception:
-            failures.append("rmse")
-            traceback.print_exc()
-        done("rmse", t0)
-
-    if only is None or "roofline" in only:
-        t0 = section("roofline: dry-run aggregation")
-        try:
-            from benchmarks import roofline
-
-            rows, md = roofline.table("pod16x16")
-            ok = sum(1 for r in rows if r.get("status") == "ok")
-            print(f"{ok}/{len(rows)} cells aggregated (full table: "
-                  "experiments/bench/roofline_pod16x16.json)")
-        except Exception:
-            failures.append("roofline")
-            traceback.print_exc()
-        done("roofline", t0)
 
     print("\n==== benchmark summary ====")
     print("FAILURES:", failures or "none")

@@ -18,6 +18,9 @@ still divides the same global device total — S is preserved, so the
 checkpointed ring carries reshard onto the new process-spanning mesh and
 the samples continue bitwise-identically. ``--num-processes 1`` runs the
 child directly with no coordinator (plain single-process path).
+
+This is a CPU-gang tool: every child runs with ``JAX_PLATFORMS=cpu``, also
+on a host with a TPU, because N processes cannot share one chip.
 """
 from __future__ import annotations
 
@@ -36,8 +39,10 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         prog="python scripts/launch_multiproc.py",
-        description="Run repro.launch.bpmf as N local jax processes "
-                    "(args after -- are forwarded to every process).",
+        description="Run repro.launch.bpmf as N local jax processes on "
+                    "the host CPU (JAX_PLATFORMS=cpu in every child, also "
+                    "on a TPU host); args after -- are forwarded to every "
+                    "process.",
     )
     p.add_argument("--num-processes", type=int, default=2)
     p.add_argument("--devices-per-process", type=int, default=4,
@@ -78,6 +83,7 @@ def run_once(num_processes: int, devices: int, forward: list[str],
     immediately (the cluster-manager behavior the restart policy assumes).
     """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"  # N processes cannot share one chip
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(REPO_ROOT, "src"), env.get("PYTHONPATH", "")]
     ).rstrip(os.pathsep)

@@ -1,6 +1,8 @@
 #!/usr/bin/env bash
 # Tier-1 test entry point.
 #
+# Runs on the CPU (JAX_PLATFORMS=cpu, also on a host with a TPU: the chip
+# is reached by `python chip_smoke.py [--chips 4]`, not by the tests).
 # Forces 8 host (CPU) devices so the distributed/ring code paths exercise a
 # real multi-device mesh, and puts src/ on PYTHONPATH. Subprocess-based
 # multidevice tests override the device count themselves
@@ -54,6 +56,7 @@ if [[ "${XLA_FLAGS:-}" != *xla_force_host_platform_device_count* ]]; then
   export XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=8"
 fi
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+export JAX_PLATFORMS=cpu
 
 BENCH_SMOKE=0
 AUTOTUNE_SMOKE=0

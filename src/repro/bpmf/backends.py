@@ -299,6 +299,17 @@ class Backend(abc.ABC):
             ``(rmse_sample, rmse_avg, sweep)`` rows.
         """
 
+    def lower_block(
+        self, key: jax.Array, state, pred: PredictionState,
+        accum: PosteriorAccum, block_size: int,
+    ) -> jax.stages.Lowered:
+        """The program :meth:`sweep_block` dispatches, lowered, not run.
+
+        ``.compile()`` of the result gives the compile time, the HLO and
+        ``memory_analysis()`` of the block the run loop executes.
+        """
+        raise NotImplementedError(f"backend {self.name!r} runs no single block program")
+
     @abc.abstractmethod
     def factors(self, state) -> tuple[np.ndarray, np.ndarray]:
         """(U, V) as host arrays in *original* item order."""
@@ -414,16 +425,24 @@ class SequentialBackend(Backend):
     def sweep(self, key: jax.Array, state, pred: PredictionState):
         return gibbs.gibbs_sweep(key, state, pred, self.data, self.core_cfg)
 
+    def _block_fn(self):
+        if self.donate_blocks:
+            return gibbs.gibbs_sweep_block_donated
+        return gibbs.gibbs_sweep_block
+
     def sweep_block(
         self, key: jax.Array, state, pred: PredictionState,
         accum: PosteriorAccum, block_size: int,
     ):
-        fn = (
-            gibbs.gibbs_sweep_block_donated
-            if self.donate_blocks
-            else gibbs.gibbs_sweep_block
+        return self._block_fn()(key, state, pred, accum, self.data, self.core_cfg, block_size)
+
+    def lower_block(
+        self, key: jax.Array, state, pred: PredictionState,
+        accum: PosteriorAccum, block_size: int,
+    ) -> jax.stages.Lowered:
+        return self._block_fn().lower(
+            key, state, pred, accum, self.data, self.core_cfg, block_size
         )
-        return fn(key, state, pred, accum, self.data, self.core_cfg, block_size)
 
     def factors(self, state) -> tuple[np.ndarray, np.ndarray]:
         return np.asarray(state.U), np.asarray(state.V)
@@ -521,19 +540,30 @@ class DistributedBackend(Backend):
     def init_state(self, key: jax.Array):
         return dist.init_dist_state(key, self.data, self.core_cfg, self.mesh)
 
+    def init_pred(self) -> PredictionState:
+        return dist.replicate(self.mesh, super().init_pred())
+
     def sweep(self, key: jax.Array, state, pred: PredictionState):
         return dist.dist_gibbs_sweep(key, state, pred, self.data, self.core_cfg, self.mesh)
+
+    def _block_fn(self):
+        if self.donate_blocks:
+            return dist.dist_gibbs_sweep_block_donated
+        return dist.dist_gibbs_sweep_block
 
     def sweep_block(
         self, key: jax.Array, state, pred: PredictionState,
         accum: PosteriorAccum, block_size: int,
     ):
-        fn = (
-            dist.dist_gibbs_sweep_block_donated
-            if self.donate_blocks
-            else dist.dist_gibbs_sweep_block
+        return self._block_fn()(
+            key, state, pred, accum, self.data, self.core_cfg, self.mesh, block_size
         )
-        return fn(
+
+    def lower_block(
+        self, key: jax.Array, state, pred: PredictionState,
+        accum: PosteriorAccum, block_size: int,
+    ) -> jax.stages.Lowered:
+        return self._block_fn().lower(
             key, state, pred, accum, self.data, self.core_cfg, self.mesh, block_size
         )
 

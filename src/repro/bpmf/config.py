@@ -52,14 +52,15 @@ class ModelConfig:
         alpha: Rating noise precision (likelihood ``N(r | u·v, 1/alpha)``).
         beta0: Normal-Wishart prior strength on the factor means.
         sample_dtype: dtype of the stored factor samples.
-        compute_dtype: dtype of the Gram contraction (bf16 on TPU).
+        compute_dtype: dtype of the Gram contraction operands: f32 (the
+            default) or bf16; accumulation is f32 either way.
     """
 
     K: int = 32
     alpha: float = 2.0  # rating noise precision
     beta0: float = 2.0  # Normal-Wishart prior strength
     sample_dtype: Any = jnp.float32
-    compute_dtype: Any = jnp.float32  # Gram contraction dtype (bf16 on TPU)
+    compute_dtype: Any = jnp.float32  # Gram contraction dtype (f32 or bf16)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -267,6 +267,19 @@ class BPMFEngine:
             yielded = len(self.history)
             yield from block
 
+    def lower_block(self) -> jax.stages.Lowered:
+        """The next device block's program, lowered for the current carry.
+
+        Tracing happens here, so the Gram dispatch decisions of the block
+        can be listed with ``repro.kernels.ops.record_gram_decisions``;
+        ``.compile()`` gives the compile time, the HLO and
+        ``memory_analysis()`` of what :meth:`sample` will run. Runs nothing.
+        """
+        self._ensure_state()
+        return self.backend.lower_block(
+            self._k_run, self._state, self._pred, self._accum, self._next_block_len()
+        )
+
     def fit(self, data: RatingsCOO | None = None, resume: bool = False) -> "BPMFEngine":
         """Run (or finish) all sweeps.
 

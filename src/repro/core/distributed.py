@@ -37,28 +37,10 @@ import dataclasses
 import functools
 from typing import Any, Sequence
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:  # jax >= 0.5: public top-level API
-    from jax import shard_map as _shard_map
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the "skip replication check" kwarg was renamed check_rep -> check_vma
-_SHARD_MAP_CHECK_KW = (
-    "check_vma" if "check_vma" in inspect.signature(_shard_map).parameters else "check_rep"
-)
-
-
-def shard_map(f, mesh, in_specs, out_specs, **kwargs):
-    kwargs.setdefault(_SHARD_MAP_CHECK_KW, False)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -894,13 +876,17 @@ def init_dist_state(
     init = jax.jit(functools.partial(init_rows, K=cfg.K, dtype=dt), out_shardings=sharding)
     U = init(ku, data.users.orig_ids)
     V = init(kv, data.movies.orig_ids)
-    return DistState(
-        U=U,
-        V=V,
-        hyper_U=HyperParams.init(cfg.K, dt),
-        hyper_V=HyperParams.init(cfg.K, dt),
-        sweep=jnp.zeros((), jnp.int32),
-    )
+    # replicated like the block's outputs, so the second block reuses the
+    # first block's executable instead of compiling for new input shardings
+    rep = replicate(mesh, (HyperParams.init(cfg.K, dt), HyperParams.init(cfg.K, dt),
+                           jnp.zeros((), jnp.int32)))
+    return DistState(U=U, V=V, hyper_U=rep[0], hyper_V=rep[1], sweep=rep[2])
+
+
+def replicate(mesh: Mesh, tree):
+    """Place every leaf of ``tree`` replicated over ``mesh``."""
+    rep = NamedSharding(mesh, P())
+    return jax.tree_util.tree_map(lambda x: place_global(x, rep), tree)
 
 
 def _bucket_specs(side: RingSide) -> RingSide:
@@ -982,6 +968,7 @@ def dist_gibbs_sweep(
             data_specs(data),
         ),
         out_specs=(ring, ring, hyper_spec, hyper_spec, rep, rep, rep, rep),
+        check_vma=False,
     )
     U, V, hU, hV, sweep, psum_, pn, r = fn(
         key, state.U, state.V, state.sweep, pred_state.sum_pred, pred_state.num_samples, data
@@ -1061,6 +1048,7 @@ def _dist_gibbs_sweep_block(
             data_specs(data),
         ),
         out_specs=(ring, ring, hyper_spec, hyper_spec, rep, rep, rep, accum_specs(), rep),
+        check_vma=False,
     )
     U, V, hU, hV, sweep, psum_, pn, accum, metrics = fn(
         key, state.U, state.V, state.hyper_U, state.hyper_V, state.sweep,

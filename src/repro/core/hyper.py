@@ -26,6 +26,8 @@ from jax.scipy.linalg import solve_triangular
 
 from repro.core.types import HyperParams, NormalWishartPrior
 
+_F32 = jax.lax.Precision.HIGHEST  # f32 products on the TPU too, as on the CPU
+
 
 def _sample_wishart(key: jax.Array, scale_chol: jax.Array, df: jax.Array) -> jax.Array:
     """Sample from Wishart(scale, df) given chol(scale) via Bartlett.
@@ -41,8 +43,8 @@ def _sample_wishart(key: jax.Array, scale_chol: jax.Array, df: jax.Array) -> jax
     diag = jnp.sqrt(chi2)
     normals = jax.random.normal(kn, (K, K), dtype=scale_chol.dtype)
     A = jnp.tril(normals, -1) + jnp.diag(diag)
-    LA = scale_chol @ A
-    return LA @ LA.T
+    LA = jnp.matmul(scale_chol, A, precision=_F32)
+    return jnp.matmul(LA, LA.T, precision=_F32)
 
 
 def hyper_sufficient_stats(
@@ -57,13 +59,13 @@ def hyper_sufficient_stats(
     if weights is None:
         n = jnp.asarray(X.shape[0], dtype)
         sx = jnp.sum(X, axis=0)
-        sxx = X.T @ X
+        sxx = jnp.matmul(X.T, X, precision=_F32)
     else:
         w = weights.astype(dtype)
         n = jnp.sum(w)
         Xw = X * w[:, None]
         sx = jnp.sum(Xw, axis=0)
-        sxx = Xw.T @ X
+        sxx = jnp.matmul(Xw.T, X, precision=_F32)
     return n, sx, sxx
 
 

@@ -82,7 +82,8 @@ def sample_from_terms(
     """Draw x_i ~ N(P^-1 l, P^-1) for a batch of items from accumulated terms."""
     K = g.shape[-1]
     prec = G + hyper.Lam  # [B, K, K]
-    lin = g + hyper.Lam @ hyper.mu  # [B, K] (broadcast add of [K])
+    lam_mu = jnp.matmul(hyper.Lam, hyper.mu, precision=jax.lax.Precision.HIGHEST)
+    lin = g + lam_mu  # [B, K] (broadcast add of [K])
     L = jnp.linalg.cholesky(prec)
     # mean = P^-1 lin via two triangular solves
     y = solve_triangular(L, lin[..., None], lower=True)
@@ -105,10 +106,18 @@ def update_bucket(
     """Sample all items of one bucket and scatter them into X_side.
 
     Bucket rows with ``item_ids == -1`` are padding and dropped by the
-    scatter (mode="drop").
+    scatter (mode="drop"). The bucket is drawn in row tiles
+    (:meth:`~repro.core.types.Bucket.row_tiles`) under ``lax.map``; every
+    item's draw depends only on its own row and id, so the samples are those
+    of the bucket in one piece.
     """
-    G, g = gram_terms(X_opp, bucket, alpha, compute_dtype, gram_impl)
-    new = sample_from_terms(key, bucket.item_ids, G, g, hyper)
+
+    def draw(b: Bucket) -> jax.Array:
+        G, g = gram_terms(X_opp, b, alpha, compute_dtype, gram_impl)
+        return sample_from_terms(key, b.item_ids, G, g, hyper)
+
+    tiled = jax.lax.map(draw, bucket.row_tiles())
+    new = tiled.reshape(-1, tiled.shape[-1])[: bucket.B]  # drop dead rows
     return X_side.at[bucket.item_ids].set(new.astype(X_side.dtype), mode="drop")
 
 

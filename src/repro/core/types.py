@@ -17,6 +17,17 @@ import numpy as np
 
 from repro.utils import pytree_dataclass, static_field
 
+#: Neighbor slots (rows x pad) per Gram tile. Buckets are processed in row
+#: tiles of at most this many slots, so the per-item temporaries — the gathered
+#: ``[rows, P, K+1]`` block and the ``[rows, K, K]`` Gram and Cholesky terms —
+#: are bounded by one tile and not by the biggest pad class of a side.
+GRAM_TILE_SLOTS = 1 << 16
+
+
+def gram_tile_rows(P: int) -> int:
+    """Rows per Gram tile for a bucket of pad ``P`` (:data:`GRAM_TILE_SLOTS`)."""
+    return max(1, GRAM_TILE_SLOTS // max(int(P), 1))
+
 
 @pytree_dataclass
 class NormalWishartPrior:
@@ -138,6 +149,31 @@ class Bucket:
     def mask(self) -> jax.Array:
         return (jnp.arange(self.P, dtype=jnp.int32)[None, :] < self.nnz[:, None]).astype(self.val.dtype)
 
+    def row_tiles(self) -> "Bucket":
+        """This bucket as ``[n, rows]`` tiles for ``lax.map`` / ``lax.scan``,
+        with ``rows = min(B, gram_tile_rows(P))``: a bucket within one tile
+        is one tile of its own rows.
+
+        The last tile is padded with dead rows: ``item_ids == -1`` and
+        ``nnz == 0``, so their Gram terms are exact zeros. A caller that
+        *sets* rows slices them off; ``.at[-1]`` would wrap to the last row.
+        """
+        rows = max(1, min(self.B, gram_tile_rows(self.P)))
+        n = -(-self.B // rows)
+        extra = n * rows - self.B
+
+        def tile(x: jax.Array, fill: int) -> jax.Array:
+            pads = [(0, extra)] + [(0, 0)] * (x.ndim - 1)
+            x = jnp.pad(x, pads, constant_values=fill)
+            return x.reshape((n, rows) + x.shape[1:])
+
+        return Bucket(
+            item_ids=tile(self.item_ids, -1),
+            nbr=tile(self.nbr, 0),
+            val=tile(self.val, 0),
+            nnz=tile(self.nnz, 0),
+        )
+
 
 @pytree_dataclass
 class BucketedSide:
@@ -199,7 +235,7 @@ class BPMFConfig:
     comm_mode: str = "ring"
     pipeline_depth: int = 1  # ring_async only: ppermutes in flight (d >= 1)
     sample_dtype: Any = jnp.float32
-    compute_dtype: Any = jnp.float32  # contraction dtype (bf16 on TPU)
+    compute_dtype: Any = jnp.float32  # Gram contraction dtype (f32 or bf16)
     # Gram dispatch: "auto" (autotune cache -> heuristic), "pallas_fused"
     # (one fused kernel per ring step), "pallas" (per-bucket kernel), "xla"
     gram_impl: str = "auto"

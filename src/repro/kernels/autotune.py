@@ -62,8 +62,18 @@ _VMEM_BUDGET = 12 * 2**20  # leave headroom below the ~16 MB/core VMEM
 _MXU_GATHER_ADVANTAGE = 32.0
 _FUSED_DISPATCH_DISCOUNT = 8.0
 
-_TB_CANDIDATES = (8, 4, 2, 1)
+# What Mosaic accepts for the v5e (tests/test_chip_compile.py compiles every
+# decision): the (tb, pc) index blocks tile in (8, 128) units, so tb below 8
+# is refused. Chunks up to 256 wide gather without holding the one-hot; a
+# wider one puts the whole f32 [tb, pc, Ns] one-hot on VMEM's stack, which
+# is refused above 4 MiB even inside the working-set budget.
+_TB_CANDIDATES = (8,)
 _PC_CANDIDATES = (512, 256, 128)
+_STACK_ONEHOT_BYTES = 4 * 2**20
+
+
+def _onehot_fits_stack(tb: int, pc: int, ns: int) -> bool:
+    return pc <= 256 or tb * pc * ns * 4 <= _STACK_ONEHOT_BYTES
 
 
 def _dtype_name(compute_dtype: Any) -> str:
@@ -310,7 +320,10 @@ def pick_tiling(
         for pc in _PC_CANDIDATES:
             if pc > round_up(max(P, 1), 128) and pc != _PC_CANDIDATES[-1]:
                 continue  # don't tile wider than the (padded) row
-            if vmem_bytes_estimate(tb, pc, Ns, K, None, compute_dtype, cap) <= _VMEM_BUDGET:
+            if (
+                vmem_bytes_estimate(tb, pc, Ns, K, None, compute_dtype, cap) <= _VMEM_BUDGET
+                and _onehot_fits_stack(tb, pc, Ns)
+            ):
                 return tb, pc
     return None
 
@@ -468,7 +481,10 @@ def measure_step(
     G, g, X, buckets = _synthetic_step(bucket_shapes, Ns, K, cap, compute_dtype)
 
     if tilings is None:
-        tilings = [(tb, pc) for tb in (8, 4) for pc in (128, 256, 512)]
+        tilings = [
+            (tb, pc) for tb in _TB_CANDIDATES for pc in _PC_CANDIDATES
+            if _onehot_fits_stack(tb, pc, Ns)
+        ]
 
     import functools
 

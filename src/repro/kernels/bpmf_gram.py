@@ -47,6 +47,19 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def mxu_precision(compute_dtype) -> jax.lax.Precision:
+    """Contraction precision for a Gram of ``compute_dtype`` operands.
+
+    f32 operands get ``HIGHEST``: at ``DEFAULT`` the TPU multiplies f32 in
+    one bfloat16 pass, which rounds the gathered rows themselves. bf16
+    operands are exact in one pass, so they keep ``DEFAULT``. The CPU
+    computes f32 dots in f32 either way.
+    """
+    if jnp.dtype(compute_dtype) == jnp.float32:
+        return jax.lax.Precision.HIGHEST
+    return jax.lax.Precision.DEFAULT
+
+
 def _gather_chunk(nbr, valid, x, base, compute_dtype):
     """One-hot MXU gather of one (rows, pc) chunk against one Ns slice.
 
@@ -64,12 +77,31 @@ def _gather_chunk(nbr, valid, x, base, compute_dtype):
     T, pc = nbr.shape
     ns = x.shape[0]
     row_ids = base + jax.lax.broadcasted_iota(jnp.int32, (T, pc, ns), 2)
-    onehot = (nbr[:, :, None] == row_ids).astype(compute_dtype)
-    onehot = onehot * valid.astype(compute_dtype)[:, :, None]
+    # mask and broadcast in f32: Mosaic cannot reshape packed bf16 vregs
+    onehot = (nbr[:, :, None] == row_ids).astype(jnp.float32)
+    onehot = (onehot * valid.astype(jnp.float32)[:, :, None]).astype(compute_dtype)
     return jax.lax.dot_general(
         onehot, x.astype(compute_dtype), (((2,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        precision=mxu_precision(compute_dtype), preferred_element_type=jnp.float32,
     )
+
+
+def _gram(xg, compute_dtype):
+    """``[T, pc, K]`` f32 gathered rows -> ``[T, K, K]`` f32 ``Xg^T Xg``."""
+    xg = xg.astype(compute_dtype)
+    return jax.lax.dot_general(
+        xg, xg, (((1,), (1,)), ((0,), (0,))),
+        precision=mxu_precision(compute_dtype), preferred_element_type=jnp.float32,
+    )
+
+
+def _linear(xg, val, mask, compute_dtype):
+    """``[T, K]`` f32 ``Xg^T (val * mask)``; the broadcast stays in f32."""
+    vm = (val * mask.astype(val.dtype))[:, :, None].astype(compute_dtype)
+    return jax.lax.dot_general(
+        xg.astype(compute_dtype), vm, (((1,), (1,)), ((0,), (0,))),
+        precision=mxu_precision(compute_dtype), preferred_element_type=jnp.float32,
+    )[:, :, 0]
 
 
 def _gram_kernel(
@@ -106,15 +138,8 @@ def _gram_kernel(
 
     @pl.when(n == num_ns - 1)
     def _contract():
-        xg = xg_ref[...].astype(compute_dtype)
-        G_ref[...] += jax.lax.dot_general(
-            xg, xg, (((1,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
-        )
-        vm = (val_ref[...] * mask.astype(val_ref.dtype)).astype(compute_dtype)
-        g_ref[...] += jax.lax.dot_general(
-            xg, vm[:, :, None], (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )[:, :, 0]
+        G_ref[...] += _gram(xg_ref[...], compute_dtype)
+        g_ref[...] += _linear(xg_ref[...], val_ref[...], mask, compute_dtype)
 
 
 @functools.partial(
@@ -218,15 +243,8 @@ def _fused_kernel(
     @pl.when(n == num_ns - 1)
     def _contract_and_scatter():
         a = jnp.asarray(alpha, jnp.float32)
-        xg = xg_ref[...].astype(compute_dtype)
-        Gp = a * jax.lax.dot_general(
-            xg, xg, (((1,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
-        )  # [TB, K, K]
-        vm = (val_ref[...] * mask.astype(val_ref.dtype)).astype(compute_dtype)
-        gp = a * jax.lax.dot_general(
-            xg, vm[:, :, None], (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )[:, :, 0]  # [TB, K]
+        Gp = a * _gram(xg_ref[...], compute_dtype)  # [TB, K, K]
+        gp = a * _linear(xg_ref[...], val_ref[...], mask, compute_dtype)  # [TB, K]
         items = item_ref[...]
         for j in range(tb):  # tb is small and static: unrolled scatter
             idx = items[j, 0]

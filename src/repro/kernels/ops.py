@@ -8,18 +8,60 @@ Each op picks the best implementation for the current shape and backend via
   - ``"pallas"``: the per-bucket one-hot MXU kernel;
   - ``"xla"``: gather + einsum (``ref.py`` is the semantic ground truth).
 
-On CPU (this container) the Pallas paths run in interpret mode for tests;
-the heuristic therefore defaults to ``"xla"`` off-TPU and only a warmed
-autotune cache (or an explicit ``gram_impl``) selects a kernel.
+Off the TPU the Pallas paths run in interpret mode (:func:`pallas_interpret`)
+for tests; the heuristic therefore defaults to ``"xla"`` off-TPU and only a
+warmed autotune cache (or an explicit ``gram_impl``) selects a kernel. An
+explicit kernel whose working set cannot be tiled raises instead of
+silently running XLA; :func:`record_gram_decisions` lists what a trace
+resolved to.
 """
 from __future__ import annotations
+
+import contextlib
+import contextvars
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import autotune, ref
-from repro.kernels.bpmf_gram import bpmf_gram_fused, bpmf_gram_pallas, vmem_bytes_estimate
+from repro.kernels.bpmf_gram import (
+    bpmf_gram_fused, bpmf_gram_pallas, mxu_precision, vmem_bytes_estimate,
+)
 from repro.utils import round_up
+
+# the list record_gram_decisions() yields, while its block is open
+_DECISIONS: contextvars.ContextVar[list | None] = contextvars.ContextVar(
+    "gram_decisions", default=None
+)
+
+
+def pallas_interpret() -> bool:
+    """Whether the Pallas kernels run in interpret mode: off the TPU only."""
+    return jax.default_backend() != "tpu"
+
+
+@contextlib.contextmanager
+def record_gram_decisions():
+    """Collect every Gram dispatch decision traced inside the ``with`` block.
+
+    Yields a list that fills with ``(kind, (B, P, Ns, K), Decision)`` tuples:
+    ``kind`` is ``"bucket"`` for one per-bucket (or per-row-tile) dispatch
+    and ``"step"`` for one fused ring step. Only tracing records, so lower
+    or first-call the program inside the block.
+    """
+    decisions: list = []
+    token = _DECISIONS.set(decisions)
+    try:
+        yield decisions
+    finally:
+        _DECISIONS.reset(token)
+
+
+def _record(kind: str, shape: tuple, dec: autotune.Decision) -> None:
+    decisions = _DECISIONS.get()
+    if decisions is not None:
+        decisions.append((kind, shape, dec))
+
 
 # re-exported for back-compat: the tiling choice lives with the autotuner now
 pick_tiling = autotune.pick_tiling
@@ -53,7 +95,10 @@ def _bpmf_gram_xla(
     mask = (jnp.arange(P, dtype=jnp.int32)[None, :] < nnz[:, None]).astype(compute_dtype)
     Xn = jnp.take(X, nbr, axis=0).astype(compute_dtype) * mask[..., None]
     Y = jnp.concatenate([Xn, val.astype(compute_dtype)[..., None]], axis=-1)
-    Z = jnp.einsum("bpi,bpj->bij", Y, Y, preferred_element_type=jnp.float32)
+    Z = jnp.einsum(
+        "bpi,bpj->bij", Y, Y,
+        precision=mxu_precision(compute_dtype), preferred_element_type=jnp.float32,
+    )
     return Z[:, :-1, :-1].astype(jnp.float32), Z[:, :-1, -1].astype(jnp.float32)
 
 
@@ -68,9 +113,12 @@ def _fill_tiling(
 ) -> autotune.Decision:
     """Complete a pallas decision's missing (tb, pc, ns_chunk) fields.
 
-    Returns ``None`` when the working set cannot fit the VMEM budget even
-    streamed (``chunked_tiling``'s contract) — callers fall back to XLA.
     Explicit ``tb`` *and* ``pc`` are trusted verbatim (tests/benchmarks).
+
+    Raises:
+        ValueError: The working set cannot fit the VMEM budget even
+            streamed (``chunked_tiling``'s contract). A kernel that was
+            asked for never turns into XLA behind the caller's back.
     """
     tb, pc, ns = dec.tb, dec.pc, dec.ns_chunk
     if tb is not None and pc is not None:
@@ -80,7 +128,10 @@ def _fill_tiling(
         return autotune.Decision(dec.impl, tb or tiling[0], pc or tiling[1], ns)
     chunked = autotune.chunked_tiling(B, P, Ns, K, compute_dtype, cap)
     if chunked is None:
-        return None
+        raise ValueError(
+            f"gram impl {dec.impl!r} cannot be tiled into the VMEM budget at "
+            f"B={B} P={P} Ns={Ns} K={K} cap={cap}; use gram_impl='xla' or 'auto'"
+        )
     return autotune.Decision(
         dec.impl, tb or chunked[0], pc or chunked[1], ns or chunked[2]
     )
@@ -106,12 +157,12 @@ def bpmf_gram(
     ``"xla"``; explicit ``tb``/``pc``/``ns_chunk`` override the decision's
     tiling. ``force_pallas`` is the legacy boolean override (maps to
     ``impl``). When the shard exceeds the VMEM budget the kernel streams it
-    in ``ns_chunk`` rows instead of falling back to XLA.
+    in ``ns_chunk`` rows; when even that cannot fit, a Pallas impl raises.
     """
     B, P = nbr.shape
     Ns, K = X.shape
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     if force_pallas is not None:
         impl = "pallas" if force_pallas else "xla"
     if impl == "auto":
@@ -129,7 +180,8 @@ def bpmf_gram(
             ),
             B, P, Ns, K, compute_dtype,
         )
-    if dec is None or dec.impl == "xla":
+    _record("bucket", (B, P, Ns, K), dec)
+    if dec.impl == "xla":
         return _bpmf_gram_xla(X, nbr, val, nnz, compute_dtype)
     nbr_p = _pad_axis(_pad_axis(nbr, 1, dec.pc), 0, dec.tb)
     val_p = _pad_axis(_pad_axis(val, 1, dec.pc), 0, dec.tb)
@@ -220,17 +272,20 @@ def bpmf_gram_step(
         compute_dtype: Contraction dtype.
         gram_impl: ``"auto" | "pallas_fused" | "pallas" | "xla"``.
         tb / pc / ns_chunk: Explicit tiling overrides (tests/benchmarks).
-        interpret: Pallas interpret mode (default: off-TPU).
+        interpret: Pallas interpret mode (default: :func:`pallas_interpret`).
 
     Returns:
         Updated ``(G, g)``.
+
+    Raises:
+        ValueError: An explicit Pallas ``gram_impl`` cannot be tiled.
     """
     if not buckets:
         return G, g
     Ns, K = X_src.shape
     cap = G.shape[0]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     shapes = [(b.B, b.P) for b in buckets]
     per_bucket_auto = False
     if gram_impl == "auto":
@@ -258,12 +313,7 @@ def bpmf_gram_step(
             autotune.Decision(dec.impl, tb or dec.tb, pc or dec.pc, ns_chunk or dec.ns_chunk),
             B_tot, P_max, Ns, K, compute_dtype, cap,
         )
-        if dec is None:
-            # fused accumulator windows don't fit: degrade to the
-            # per-bucket kernel (cap-independent), whose own dispatch
-            # still falls back to XLA if even streaming cannot fit
-            dec = autotune.Decision("pallas")
-    if dec.impl == "pallas_fused":
+        _record("step", (B_tot, P_max, Ns, K), dec)
         nbr, val, item, cnt = flatten_step(buckets, dec.pc, dec.tb)
         X_p = _pad_axis(X_src, 0, dec.ns_chunk) if dec.ns_chunk else X_src
         return bpmf_gram_fused(
@@ -273,25 +323,30 @@ def bpmf_gram_step(
         )
 
     a = jnp.asarray(alpha, jnp.float32)
-    for b in buckets:
-        if per_bucket_auto:
-            # bucket-class dispatch: bpmf_gram resolves this bucket's own
-            # autotune.bucket_key (cache hit or heuristic), so different
-            # pad classes of the same step can take different impls
-            Gb, gb = bpmf_gram(
-                X_src, b.nbr, b.val, b.nnz,
-                compute_dtype=compute_dtype, impl="auto",
-                tb=tb, pc=pc, ns_chunk=ns_chunk, interpret=interpret,
-            )
-        else:
-            # dispatch per bucket so the decision's (tb, pc, ns_chunk) —
-            # from the cache or explicit overrides — reaches the kernel
-            Gb, gb = bpmf_gram(
-                X_src, b.nbr, b.val, b.nnz,
-                compute_dtype=compute_dtype, impl=dec.impl,
-                tb=tb or dec.tb, pc=pc or dec.pc,
-                ns_chunk=ns_chunk or dec.ns_chunk, interpret=interpret,
-            )
+    if per_bucket_auto:
+        # bucket-class dispatch: bpmf_gram resolves this bucket's own
+        # autotune.bucket_key (cache hit or heuristic), so different pad
+        # classes of the same step can take different impls
+        kw = dict(impl="auto", tb=tb, pc=pc, ns_chunk=ns_chunk)
+    else:
+        # dispatch per bucket so the decision's (tb, pc, ns_chunk) — from
+        # the cache or explicit overrides — reaches the kernel
+        kw = dict(impl=dec.impl, tb=tb or dec.tb, pc=pc or dec.pc,
+                  ns_chunk=ns_chunk or dec.ns_chunk)
+
+    def add(carry, b):
+        G, g = carry
+        Gb, gb = bpmf_gram(
+            X_src, b.nbr, b.val, b.nnz,
+            compute_dtype=compute_dtype, interpret=interpret, **kw,
+        )
         G = G.at[b.item_ids].add(a * Gb, mode="drop")
         g = g.at[b.item_ids].add(a * gb, mode="drop")
+        return (G, g), None
+
+    for b in buckets:
+        # buckets run in row tiles so the [rows, K, K] Gram terms never grow
+        # with the bucket; each item occurs in one row, so the scatter-adds
+        # commute and the sums are unchanged
+        (G, g), _ = jax.lax.scan(add, (G, g), b.row_tiles())
     return G, g

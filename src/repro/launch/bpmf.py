@@ -24,7 +24,7 @@ import os
 import sys
 import time
 
-from repro.launch.hostdevices import init_multiprocess
+from repro.launch.hostdevices import enable_compile_cache, init_multiprocess
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -119,6 +119,8 @@ def main(argv: list[str] | None = None) -> int:
     )
 
     import jax
+
+    enable_compile_cache()
 
     from repro.bpmf import BPMFConfig, BPMFEngine, load_dataset
     from repro.runtime.elastic import FailureInjector, StepTimer

@@ -14,12 +14,19 @@ count/id from CLI flags or the ``REPRO_COORDINATOR`` / ``REPRO_NUM_PROCESSES``
 ``scripts/launch_multiproc.py`` uses). Call order matters: the host device
 count must be forced first, then the distributed service initialized, and
 only then may any jax backend spin up.
+
+:func:`enable_compile_cache` is the one place the persistent compilation
+cache is configured; entry points call it, library code and tests do not.
 """
 from __future__ import annotations
 
 import os
 import re
 import sys
+
+REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+)
 
 
 def multiprocess_active() -> bool:
@@ -116,3 +123,23 @@ def init_multiprocess(
         process_id=process_id,
     )
     return True
+
+
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is jax's own setting: it is
+    left to jax and nothing is set here. Otherwise the cache lives at the
+    fixed ``<repo>/.jax_cache`` (git-ignored), so a later run of the same
+    program finds its entries. Called by the entry points only
+    (``repro.launch.bpmf``, ``repro.launch.serve``,
+    ``repro.launch.serve_server``, ``chip_smoke.py``).
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -35,7 +35,7 @@ import argparse
 import json
 import sys
 
-from repro.launch.hostdevices import force_host_device_count
+from repro.launch.hostdevices import enable_compile_cache, force_host_device_count
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -120,6 +120,7 @@ def main(argv: list[str] | None = None) -> int:
             except ServeConnectionError as e:
                 return {"error": f"{type(e).__name__}: {e}"}
     else:
+        enable_compile_cache()  # only this mode compiles
         try:
             predictor = PosteriorPredictor.load(args.artifact)
         except ArtifactError as e:

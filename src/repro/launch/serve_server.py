@@ -25,7 +25,7 @@ import argparse
 import signal
 import sys
 
-from repro.launch.hostdevices import force_host_device_count
+from repro.launch.hostdevices import enable_compile_cache, force_host_device_count
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -63,6 +63,7 @@ def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
 
     force_host_device_count(args.devices)
+    enable_compile_cache()
 
     # heavy imports only after XLA_FLAGS is settled
     from repro.serve import ArtifactError, BPMFServer

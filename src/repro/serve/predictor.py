@@ -45,14 +45,17 @@ def _predict_pairs(U, V, rows, cols, mean, lo, hi):
 @functools.partial(jax.jit, static_argnames=("lo", "hi"))
 def _predict_pairs_std(Us, Vs, rows, cols, mean, lo, hi):
     """Std of the clipped per-sample predictions over the sample axis."""
-    preds = jnp.einsum("sbk,sbk->sb", Us[:, rows], Vs[:, cols]) + mean
+    preds = jnp.einsum(
+        "sbk,sbk->sb", Us[:, rows], Vs[:, cols], precision=jax.lax.Precision.HIGHEST
+    ) + mean
     return jnp.std(jnp.clip(preds, lo, hi), axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "lo", "hi"))
 def _top_k(U, V, users, mean, k, lo, hi):
     """Per-user catalog scores -> (ids [B, k], scores [B, k])."""
-    scores = jnp.clip(U[users] @ V.T + mean, lo, hi)
+    scores = jnp.matmul(U[users], V.T, precision=jax.lax.Precision.HIGHEST)
+    scores = jnp.clip(scores + mean, lo, hi)
     vals, ids = jax.lax.top_k(scores, k)
     return ids.astype(jnp.int32), vals
 

@@ -27,10 +27,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core.distributed import shard_map
 from repro.utils import round_up
 
 
@@ -77,7 +77,8 @@ def build_local_topk(mesh: Mesh, num_items: int):
         def shard_fn(V_loc, U, users, mean):
             idx = jax.lax.axis_index("serve")
             gid = idx * m + jnp.arange(m, dtype=jnp.int32)
-            scores = jnp.clip(U[users] @ V_loc.T + mean, lo, hi)
+            scores = jnp.matmul(U[users], V_loc.T, precision=jax.lax.Precision.HIGHEST)
+            scores = jnp.clip(scores + mean, lo, hi)
             scores = jnp.where(gid[None, :] < num_items, scores, -jnp.inf)
             vals, ids = jax.lax.top_k(scores, kl)
             return (gid[ids])[None], vals[None]
@@ -87,6 +88,7 @@ def build_local_topk(mesh: Mesh, num_items: int):
             mesh=mesh,
             in_specs=(P("serve", None), P(), P(), P()),
             out_specs=(P("serve", None, None), P("serve", None, None)),
+            check_vma=False,
         )(V_sh, U, users, mean)
 
     return local_topk

@@ -113,3 +113,20 @@ def cast_floating(tree: Any, dtype: Any) -> Any:
         return x
 
     return jax.tree_util.tree_map(_cast, tree)
+
+
+def compiled_hbm_bytes(compiled: Any) -> int:
+    """Device bytes a compiled program needs, from ``memory_analysis()``.
+
+    Arguments, plus outputs that do not alias (donated) arguments, plus
+    temporaries and generated code. It counts this one program, not what
+    else the process keeps on the device.
+    """
+    m = compiled.memory_analysis()
+    return int(
+        m.argument_size_in_bytes
+        + m.output_size_in_bytes
+        - m.alias_size_in_bytes
+        + m.temp_size_in_bytes
+        + m.generated_code_size_in_bytes
+    )

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import functools
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -277,7 +278,7 @@ def test_autotune_malformed_cache_ignored(tmp_path):
 
 
 def _iter_subjaxprs(v):
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     if isinstance(v, ClosedJaxpr):
         yield v.jaxpr
@@ -412,3 +413,88 @@ def test_warm_bucket_cache_mixes_impls_within_step(tmp_cache):
     tmp_cache.record(skey, autotune.Decision("xla"))
     closed = jax.make_jaxpr(lambda G, g, X: fn(G, g, X, buckets))(G, g, X)
     assert _count_pallas_calls(closed.jaxpr) == 0
+
+
+@pytest.mark.parametrize(
+    "impl,K,cap",
+    [("pallas_fused", 32, 4096), ("pallas", 600, 16)],
+    ids=["fused-accumulator-windows", "per-bucket-K"],
+)
+def test_explicit_kernel_that_cannot_tile_raises(impl, K, cap):
+    """An explicit Pallas impl whose working set cannot fit VMEM even with
+    the shard streamed raises; it never turns into XLA silently."""
+    rng = np.random.default_rng(12)
+    Ns = 256
+    X = jnp.asarray(rng.normal(size=(Ns, K)), jnp.float32)
+    buckets = (_bucket(rng, Ns, 8, 8, cap),)
+    G, g = jnp.zeros((cap, K, K), jnp.float32), jnp.zeros((cap, K), jnp.float32)
+    with pytest.raises(ValueError, match="cannot be tiled"):
+        ops.bpmf_gram_step(G, g, X, buckets, alpha=2.0, gram_impl=impl)
+
+
+@pytest.mark.parametrize("path", ["draw", "ring_step"])
+def test_row_tiled_bucket_is_bitwise_untiled(monkeypatch, path):
+    """A bucket above one Gram tile runs in several row tiles (``lax.map``
+    for the draw, ``lax.scan`` for a ring step's scatter-add), with a ragged
+    last tile; the result is bitwise that of the bucket as one tile."""
+    from repro.core import posterior, types
+    from repro.core.types import HyperParams
+
+    rng = np.random.default_rng(3)
+    Ns, K, B, P = 300, 8, 1003, 8
+    X = jnp.asarray(rng.normal(size=(Ns, K)), jnp.float32)
+    b = _bucket(rng, Ns, B, P, cap=B)
+    if path == "draw":
+        A = rng.normal(size=(K, K))
+        hyper = HyperParams(
+            mu=jnp.asarray(rng.normal(size=K), jnp.float32),
+            Lam=jnp.asarray(A @ A.T + K * np.eye(K), jnp.float32),
+        )
+        key = jax.random.key(1)
+
+        def fn(Xs):
+            return posterior.update_bucket(key, Xs, X, b, hyper, 2.0)
+
+        args = (jnp.zeros((B, K), jnp.float32),)
+    else:
+
+        def fn(G, g):
+            return ops.bpmf_gram_step(G, g, X, (b,), alpha=2.0, gram_impl="xla")
+
+        args = _accs(rng, B, K)
+
+    def run():  # a fresh function each time, so nothing reuses a trace
+        f = lambda *a: fn(*a)  # noqa: E731
+        return str(jax.make_jaxpr(f)(*args)), jax.jit(f)(*args)
+
+    text, whole = run()
+    assert re.search(r"\blength=1\b", text)
+    monkeypatch.setattr(types, "GRAM_TILE_SLOTS", 250 * P)
+    text, tiled = run()
+    assert re.search(r"\blength=5\b", text)  # 1003 rows in tiles of 250, the last ragged
+    for w, t in zip(jax.tree_util.tree_leaves(whole), jax.tree_util.tree_leaves(tiled)):
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(t))
+
+
+@pytest.mark.parametrize(
+    "impl,want", [("xla", ["bucket", "bucket"]), ("pallas_fused", ["step"])]
+)
+def test_record_gram_decisions_lists_each_traced_dispatch(impl, want):
+    """Tracing inside ``record_gram_decisions`` lists one entry per bucket
+    dispatch, or one per fused step; outside the block nothing is kept."""
+    rng = np.random.default_rng(5)
+    Ns, K, cap = 64, 8, 32
+    X = jnp.asarray(rng.normal(size=(Ns, K)), jnp.float32)
+    buckets = (_bucket(rng, Ns, 8, 8, cap), _bucket(rng, Ns, 4, 32, cap))
+    G, g = _accs(rng, cap, K)
+
+    def step(G, g):
+        return ops.bpmf_gram_step(G, g, X, buckets, alpha=2.0, gram_impl=impl)
+
+    with ops.record_gram_decisions() as decisions:
+        jax.make_jaxpr(step)(G, g)
+    assert [kind for kind, _, _ in decisions] == want
+    assert {dec.impl for _, _, dec in decisions} == {impl}
+    assert all(shape[2:] == (Ns, K) for _, shape, _ in decisions)
+    jax.make_jaxpr(lambda G, g: step(G, g))(G, g)
+    assert len(decisions) == len(want)
