@@ -15,7 +15,7 @@ Two sweeps on an ml-100k-shaped synthetic:
 
 The paper's >32-node degradation (BlueGene rack boundary) corresponds here
 to the pod boundary; the projection to 256/512 chips comes from the dry-run
-roofline terms (benchmarks/roofline.py), not wall time.
+roofline terms (``python -m repro.launch.dryrun``), not wall time.
 
 Run me via: python -m benchmarks.fig4_scaling (inside an
 XLA_FLAGS=--xla_force_host_platform_device_count=8 process; benchmarks.run

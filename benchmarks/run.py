@@ -5,9 +5,7 @@
 Default is smoke scale (CI-sized, minutes); --full runs the paper-scale
 variants. Every benchmark runs in its own subprocess and this process never
 imports jax, so a child can claim the one device of a TPU host; the
-multi-device ones (fig4/fig5/rmse) get forced host device counts. The
-roofline table aggregates whatever dry-run artifacts exist under
-experiments/dryrun.
+multi-device ones (fig4/fig5/rmse) get forced host device counts.
 """
 from __future__ import annotations
 
@@ -30,8 +28,6 @@ SECTIONS = {
              8, 800),
     "rmse": ("rmse: accuracy parity across all versions", "benchmarks.rmse_convergence",
              4, 800),
-    "roofline": ("roofline: dry-run aggregation", "benchmarks.roofline",
-                 None, 800),
 }
 
 

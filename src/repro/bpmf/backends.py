@@ -264,6 +264,7 @@ class Backend(abc.ABC):
         self.cfg = cfg
         self.core_cfg = cfg.core()
         self._prepared = False
+        self._layout: dict[str, dict[str, int]] | None = None
         # "auto" donates: XLA reuses the block carry's buffers on every
         # platform we run on, and samples are unaffected. "off" is the
         # fallback path for callers that re-read a block's inputs.
@@ -313,6 +314,13 @@ class Backend(abc.ABC):
     @abc.abstractmethod
     def factors(self, state) -> tuple[np.ndarray, np.ndarray]:
         """(U, V) as host arrays in *original* item order."""
+
+    def layout_stats(self) -> dict[str, dict[str, int]]:
+        """Per side: training ``ratings`` and the ``gram_slots`` that hold
+        them, counted at :meth:`prepare` (``BPMFEngine.layout_stats``)."""
+        if self._layout is None:
+            raise NotImplementedError(f"backend {self.name!r} does not count its layout")
+        return self._layout
 
     # ------------------------------------------------------------------
     @abc.abstractmethod
@@ -417,6 +425,8 @@ class SequentialBackend(Backend):
             test_fraction=self.cfg.run.test_fraction,
             seed=self.cfg.run.seed,
         )
+        self._layout = {"users": self.data.users.layout_stats(),
+                        "movies": self.data.movies.layout_stats()}
         self._prepared = True
 
     def init_state(self, key: jax.Array):
@@ -535,6 +545,10 @@ class DistributedBackend(Backend):
             )
         self.data = dist.shard_data(data, self.mesh)
         self.num_shards = S
+        self._layout = {
+            name: dist.ring_layout_stats(side, S, self.plan.total_nnz)
+            for name, side in (("users", self.data.users), ("movies", self.data.movies))
+        }
         self._prepared = True
 
     def init_state(self, key: jax.Array):

@@ -140,9 +140,19 @@ class BPMFEngine:
                     f"got different data {fingerprint} — build a new BPMFEngine"
                 )
             return self
-        self.backend.prepare(data)
+        with jax.profiler.TraceAnnotation("bpmf.prepare"):
+            self.backend.prepare(data)
         self._data_fingerprint = fingerprint
         return self
+
+    def layout_stats(self) -> dict[str, dict[str, int]]:
+        """Per side (``"users"``, ``"movies"``): the training ``ratings`` and
+        the ``gram_slots`` the Gram runs over to hold them, counted once at
+        :meth:`prepare` from the backend's layout (summed over shards on the
+        ring). ``1 - ratings / gram_slots`` is the Gram's padding share."""
+        if not self.backend.prepared:
+            raise RuntimeError("no data: call prepare(data) first")
+        return self.backend.layout_stats()
 
     def _ensure_state(self) -> None:
         if not self.backend.prepared:
@@ -187,9 +197,10 @@ class BPMFEngine:
         byte counter sees that one buffer.
         """
         n, rows = self._inflight.popleft()
-        t0 = time.perf_counter()
-        rows = np.asarray(rows)
-        self.host_blocked_s += time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("bpmf.drain"):
+            t0 = time.perf_counter()
+            rows = np.asarray(rows)
+            self.host_blocked_s += time.perf_counter() - t0
         self.host_metric_bytes += int(rows.nbytes)
         self.history.extend(
             SweepMetrics(float(r[0]), float(r[1]), float(r[2])) for r in rows
@@ -245,13 +256,14 @@ class BPMFEngine:
             # engine's current state when save() snapshots it
             while self._sweeps_done < run.num_sweeps and len(self._inflight) < depth:
                 n = self._next_block_len()
-                self._state, self._pred, self._accum, rows = self.backend.sweep_block(
-                    self._k_run, self._state, self._pred, self._accum, n
-                )
-                try:
-                    rows.copy_to_host_async()  # start the metrics transfer now
-                except AttributeError:  # backend already returned host rows
-                    pass
+                with jax.profiler.TraceAnnotation("bpmf.dispatch"):
+                    self._state, self._pred, self._accum, rows = self.backend.sweep_block(
+                        self._k_run, self._state, self._pred, self._accum, n
+                    )
+                    try:
+                        rows.copy_to_host_async()  # start the metrics transfer now
+                    except AttributeError:  # backend already returned host rows
+                        pass
                 self._inflight.append((n, rows))
                 self._sweeps_done += n
                 if every and self._sweeps_done % every == 0:

@@ -49,7 +49,10 @@ from repro.core.balance import CostModel, Partition, partition_items
 from repro.core.gibbs import SweepMetrics, sweep_keys
 from repro.core.hyper import hyper_sufficient_stats, sample_hyper_from_stats
 from repro.core.prediction import PredictionState, rmse, update_posterior_accum
-from repro.core.types import BPMFConfig, Bucket, HyperParams, PosteriorAccum
+from repro.core.types import (
+    HYPER_SCOPE, PREDICT_SCOPE, RING_SCOPE, BPMFConfig, Bucket, HyperParams, PosteriorAccum,
+    gram_slots,
+)
 from repro.data.sparse import (
     ChunkedRatings, RatingsCOO, StableMeanAccumulator, csr_from_coo, stable_mean,
     train_test_split,
@@ -126,11 +129,12 @@ class DistState:
 class DistPlan:
     """Host-side record of how the problem was partitioned (static).
 
-    ``local_shards`` / ``local_nnz`` / ``total_nnz`` are populated by the
-    per-host builder (:func:`build_distributed_data_per_host`): which ring
-    shards this process materialized and how many training ratings it kept
-    versus the global count — the allocation guard tests assert
-    ``local_nnz < total_nnz`` on every process of a multi-process run.
+    ``total_nnz`` is the count of training ratings. ``local_shards`` /
+    ``local_nnz`` are populated by the per-host builder
+    (:func:`build_distributed_data_per_host`): which ring shards this process
+    materialized and how many training ratings it kept — the allocation
+    guard tests assert ``local_nnz < total_nnz`` on every process of a
+    multi-process run.
     """
 
     part_users: Partition
@@ -322,6 +326,15 @@ def _ring_side_buckets(
     )
 
 
+def ring_layout_stats(side: RingSide, num_shards: int, ratings: int) -> dict[str, int]:
+    """Training ``ratings`` of one side and the Gram slots that hold them,
+    summed over shards: each shard runs its ``B / S`` rows of every step's
+    bucket in row tiles (:func:`repro.core.types.gram_slots`)."""
+    slots = sum(num_shards * gram_slots(b.B // num_shards, b.P)
+                for step in side.steps for b in step)
+    return {"ratings": ratings, "gram_slots": slots}
+
+
 def build_distributed_data(
     coo: RatingsCOO,
     num_shards: int,
@@ -374,7 +387,7 @@ def build_distributed_data(
         min_rating=lo,
         max_rating=hi,
     )
-    return data, DistPlan(part_u, part_m, num_shards, strategy)
+    return data, DistPlan(part_u, part_m, num_shards, strategy, total_nnz=train.nnz)
 
 
 def local_shard_range(num_shards: int, process_index: int, num_processes: int) -> range:
@@ -582,7 +595,8 @@ def _half_sweep_ring(
     buf = X_opp_loc
     for t in range(num_shards):
         if t + 1 < num_shards:
-            nxt = jax.lax.ppermute(buf, RING_AXIS, perm)  # in flight during gram
+            with jax.named_scope(RING_SCOPE):
+                nxt = jax.lax.ppermute(buf, RING_AXIS, perm)  # in flight during gram
         G, g = _accumulate_buckets(
             G, g, buf, side.steps[t], cfg.alpha, cfg.compute_dtype, cfg.gram_impl
         )
@@ -632,11 +646,16 @@ def _half_sweep_ring_async(
 
     perm = [(i, (i + 1) % num_shards) for i in range(num_shards)]
     queue = [X_opp_loc]  # queue[i] holds the buffer for step t + i
+
+    def rotate() -> None:
+        with jax.named_scope(RING_SCOPE):
+            queue.append(jax.lax.ppermute(queue[-1], RING_AXIS, perm))
+
     for _ in range(depth - 1):  # prologue: pre-issue d-1 rotations
-        queue.append(jax.lax.ppermute(queue[-1], RING_AXIS, perm))
+        rotate()
     for t in range(num_shards):
         if t + depth < num_shards:  # issue step t+d while accumulating step t
-            queue.append(jax.lax.ppermute(queue[-1], RING_AXIS, perm))
+            rotate()
         buf = queue.pop(0)
         G, g = _accumulate_buckets(
             G, g, buf, side.steps[t], cfg.alpha, cfg.compute_dtype, cfg.gram_impl
@@ -661,7 +680,8 @@ def _half_sweep_allgather(
     cap = side.cap
     K = X_opp_loc.shape[-1]
     cap_opp = X_opp_loc.shape[0]
-    X_full = jax.lax.all_gather(X_opp_loc, RING_AXIS, tiled=True)  # [S*cap_opp, K]
+    with jax.named_scope(RING_SCOPE):
+        X_full = jax.lax.all_gather(X_opp_loc, RING_AXIS, tiled=True)  # [S*cap_opp, K]
     d = jax.lax.axis_index(RING_AXIS)
 
     G = jnp.zeros((cap, K, K), jnp.float32)
@@ -690,6 +710,7 @@ def _psum_ordered(x: jax.Array) -> jax.Array:
     return jnp.sum(jax.lax.all_gather(x, RING_AXIS), axis=0)
 
 
+@jax.named_scope(HYPER_SCOPE)
 def _sample_hyper_dist(
     key: jax.Array, X_loc: jax.Array, orig_ids: jax.Array, prior
 ) -> HyperParams:
@@ -775,16 +796,17 @@ def _sweep_step_device(
     hyper_U = _sample_hyper_dist(k_hu, U_loc, data.users.orig_ids, prior)
     U_new = half(k_u, V_new, data.users, hyper_U, cfg, S)
 
-    preds = _predict_dist(
-        U_new, V_new, data.test, data.mean_rating, data.min_rating, data.max_rating, S
-    )
     new_sweep = sweep + 1
-    burned = (new_sweep > cfg.burn_in).astype(jnp.int32)
-    pred_sum = pred_sum + preds * burned
-    pred_n = pred_n + burned
-    r_sample = rmse(preds, data.test.vals)
-    avg = pred_sum / jnp.maximum(pred_n, 1).astype(jnp.float32)
-    r_avg = jnp.where(pred_n > 0, rmse(avg, data.test.vals), r_sample)
+    with jax.named_scope(PREDICT_SCOPE):
+        preds = _predict_dist(
+            U_new, V_new, data.test, data.mean_rating, data.min_rating, data.max_rating, S
+        )
+        burned = (new_sweep > cfg.burn_in).astype(jnp.int32)
+        pred_sum = pred_sum + preds * burned
+        pred_n = pred_n + burned
+        r_sample = rmse(preds, data.test.vals)
+        avg = pred_sum / jnp.maximum(pred_n, 1).astype(jnp.float32)
+        r_avg = jnp.where(pred_n > 0, rmse(avg, data.test.vals), r_sample)
     return U_new, V_new, hyper_U, hyper_V, new_sweep, pred_sum, pred_n, r_sample, r_avg
 
 

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.scipy.linalg import solve_triangular
 
-from repro.core.types import HyperParams, NormalWishartPrior
+from repro.core.types import HYPER_SCOPE, HyperParams, NormalWishartPrior
 
 _F32 = jax.lax.Precision.HIGHEST  # f32 products on the TPU too, as on the CPU
 
@@ -105,6 +105,7 @@ def sample_hyper_from_stats(
     return HyperParams(mu=mu, Lam=Lam)
 
 
+@jax.named_scope(HYPER_SCOPE)
 def sample_hyper(
     key: jax.Array,
     X: jax.Array,

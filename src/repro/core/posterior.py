@@ -24,9 +24,10 @@ import jax
 import jax.numpy as jnp
 from jax.scipy.linalg import solve_triangular
 
-from repro.core.types import Bucket, BucketedSide, HyperParams
+from repro.core.types import DRAW_SCOPE, GRAM_SCOPE, Bucket, BucketedSide, HyperParams
 
 
+@jax.named_scope(DRAW_SCOPE)
 def item_noise(key: jax.Array, item_ids: jax.Array, K: int, dtype=jnp.float32) -> jax.Array:
     """Per-item N(0, I_K) noise, independent of batch layout."""
 
@@ -43,6 +44,7 @@ def _normalize_gram_impl(gram_impl) -> str:
     return gram_impl
 
 
+@jax.named_scope(GRAM_SCOPE)
 def gram_terms(
     X_opp: jax.Array,
     bucket: Bucket,
@@ -72,6 +74,7 @@ def gram_terms(
     return a * G, a * g
 
 
+@jax.named_scope(DRAW_SCOPE)
 def sample_from_terms(
     key: jax.Array,
     item_ids: jax.Array,
@@ -117,8 +120,9 @@ def update_bucket(
         return sample_from_terms(key, b.item_ids, G, g, hyper)
 
     tiled = jax.lax.map(draw, bucket.row_tiles())
-    new = tiled.reshape(-1, tiled.shape[-1])[: bucket.B]  # drop dead rows
-    return X_side.at[bucket.item_ids].set(new.astype(X_side.dtype), mode="drop")
+    with jax.named_scope(DRAW_SCOPE):
+        new = tiled.reshape(-1, tiled.shape[-1])[: bucket.B]  # drop dead rows
+        return X_side.at[bucket.item_ids].set(new.astype(X_side.dtype), mode="drop")
 
 
 def update_side(

@@ -4,7 +4,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.types import BPMFData, PosteriorAccum, TestSet
+from repro.core.types import PREDICT_SCOPE, BPMFData, PosteriorAccum, TestSet
 from repro.utils import pytree_dataclass
 
 
@@ -34,6 +34,7 @@ def rmse(preds: jax.Array, vals: jax.Array) -> jax.Array:
     return jnp.sqrt(jnp.mean((preds - vals) ** 2))
 
 
+@jax.named_scope(PREDICT_SCOPE)
 def update_predictions(
     pred_state: PredictionState,
     U: jax.Array,
@@ -56,6 +57,7 @@ def update_predictions(
     return new_state, r_sample, r_avg
 
 
+@jax.named_scope(PREDICT_SCOPE)
 def update_posterior_accum(
     accum: PosteriorAccum, U: jax.Array, V: jax.Array, burned_in: jax.Array
 ) -> PosteriorAccum:

@@ -24,9 +24,30 @@ from repro.utils import pytree_dataclass, static_field
 GRAM_TILE_SLOTS = 1 << 16
 
 
+#: The sweep's layers, each a ``jax.named_scope`` in the program: the Gram
+#: with its neighbour gather, the per-item posterior draw, the
+#: hyper-parameter draw, the test predictions with the posterior summary, and
+#: one ring exchange. A device op's ``op_name`` metadata carries the names of
+#: the scopes it was traced under, so a profiler trace can be split by layer.
+SWEEP_SCOPES = ("bpmf_gram", "posterior_draw", "hyper_draw", "sweep_predict", "ring_step")
+GRAM_SCOPE, DRAW_SCOPE, HYPER_SCOPE, PREDICT_SCOPE, RING_SCOPE = SWEEP_SCOPES
+
+
 def gram_tile_rows(P: int) -> int:
     """Rows per Gram tile for a bucket of pad ``P`` (:data:`GRAM_TILE_SLOTS`)."""
     return max(1, GRAM_TILE_SLOTS // max(int(P), 1))
+
+
+def _tile_rows(B: int, P: int) -> int:
+    return max(1, min(B, gram_tile_rows(P)))
+
+
+def gram_slots(B: int, P: int) -> int:
+    """Neighbour slots the Gram runs over for ``B`` rows at pad ``P``: every
+    row tile of :meth:`Bucket.row_tiles` times ``P``, the dead rows that pad
+    the last tile included."""
+    rows = _tile_rows(B, P)
+    return -(-B // rows) * rows * P
 
 
 @pytree_dataclass
@@ -158,7 +179,7 @@ class Bucket:
         ``nnz == 0``, so their Gram terms are exact zeros. A caller that
         *sets* rows slices them off; ``.at[-1]`` would wrap to the last row.
         """
-        rows = max(1, min(self.B, gram_tile_rows(self.P)))
+        rows = _tile_rows(self.B, self.P)
         n = -(-self.B // rows)
         extra = n * rows - self.B
 
@@ -189,6 +210,11 @@ class BucketedSide:
 
     def total_ratings(self) -> int:
         return int(sum(np.sum(np.asarray(b.nnz)) for b in self.buckets))
+
+    def layout_stats(self) -> dict[str, int]:
+        """Training ratings of this side and the Gram slots that hold them."""
+        return {"ratings": self.total_ratings(),
+                "gram_slots": sum(gram_slots(b.B, b.P) for b in self.buckets)}
 
 
 @pytree_dataclass

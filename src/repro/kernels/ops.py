@@ -23,6 +23,7 @@ import contextvars
 import jax
 import jax.numpy as jnp
 
+from repro.core.types import GRAM_SCOPE
 from repro.kernels import autotune, ref
 from repro.kernels.bpmf_gram import (
     bpmf_gram_fused, bpmf_gram_pallas, mxu_precision, vmem_bytes_estimate,
@@ -137,6 +138,7 @@ def _fill_tiling(
     )
 
 
+@jax.named_scope(GRAM_SCOPE)
 def bpmf_gram(
     X: jax.Array,
     nbr: jax.Array,
@@ -231,6 +233,7 @@ def flatten_step(buckets, pc: int, tb: int):
     return nbr, val, item.astype(jnp.int32), cnt.astype(jnp.int32)
 
 
+@jax.named_scope(GRAM_SCOPE)
 def bpmf_gram_step(
     G: jax.Array,
     g: jax.Array,

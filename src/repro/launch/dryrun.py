@@ -14,8 +14,7 @@ os.environ["XLA_FLAGS"] = os.environ.get("REPRO_DRYRUN_XLA_FLAGS", "--xla_force_
 #   python -m repro.launch.dryrun --all --multi-pod      # 2x16x16
 #   python -m repro.launch.dryrun --bpmf                 # the paper's own program
 #
-# Results land in experiments/dryrun/<mesh>/<arch>__<shape>.json and feed
-# benchmarks/roofline.py + EXPERIMENTS.md.
+# Results land in experiments/dryrun/<mesh>/<arch>__<shape>.json.
 
 import argparse
 import json
