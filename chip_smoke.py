@@ -48,17 +48,20 @@ def check(ok: bool, what: str) -> None:
 
 def lowered_block(engine, label: str) -> str:
     """Lower and compile the engine's next block; print how its Gram
-    contractions resolved and whether the program holds a Pallas kernel.
-    Returns a one-line summary for the Gram-path report."""
+    contractions and per-item draws resolved and whether the program holds
+    a Pallas kernel for each. Returns a one-line summary for the report."""
+    import re
+
     from repro.kernels import ops
     from repro.utils import compiled_hbm_bytes
 
     t0 = time.perf_counter()
-    with ops.record_gram_decisions() as decisions:
+    with ops.record_gram_decisions() as decisions, ops.record_draw_decisions() as draws:
         lowered = engine.lower_block()
     compiled = lowered.compile()
     compile_s = time.perf_counter() - t0
     check(bool(decisions), f"{label}: tracing recorded no Gram dispatch")
+    check(bool(draws), f"{label}: tracing recorded no posterior draw")
     seen = {}
     for kind, (B, P, Ns, K), dec in decisions:
         seen.setdefault((kind, B, P, Ns, K, dec), 0)
@@ -66,14 +69,25 @@ def lowered_block(engine, label: str) -> str:
     for (kind, B, P, Ns, K, dec), n in seen.items():
         tiling = "" if dec.impl == "xla" else f" tb={dec.tb} pc={dec.pc} ns_chunk={dec.ns_chunk}"
         say(f"gram {label}: {kind} B={B} P={P} Ns={Ns} K={K} -> {dec.impl}{tiling} (x{n})")
-    kernels = "tpu_custom_call" in compiled.as_text()
+    on_kernel = sum(dec.impl != "xla" for _, _, dec in draws)
+    say(f"draw {label}: {on_kernel} of {len(draws)} traced draws on the kernel")
+    text = compiled.as_text()
+    calls = re.findall(r'custom_call_target="tpu_custom_call".*op_name="([^"]*)"', text)
+    kernels = any("posterior_draw" not in name for name in calls)
+    draw_kernels = any("posterior_draw" in name for name in calls)
+    batched_chol = len(re.findall(
+        r'= f32\[\d+,\d+,\d+[^ ]* custom-call\(.*custom_call_target="Cholesky"', text))
     wants = any(dec.impl != "xla" for _, _, dec in decisions)
     say(f"gram {label}: compiled block holds tpu_custom_call: {kernels}")
+    say(f"draw {label}: draw kernel in HLO: {draw_kernels}; batched Cholesky calls: {batched_chol}")
     check(kernels == wants, f"{label}: decisions say kernel={wants}, HLO says {kernels}")
+    check(draw_kernels == bool(on_kernel), f"{label}: draws on kernel={on_kernel}, HLO says {draw_kernels}")
+    check(batched_chol == 0 or on_kernel < len(draws), f"{label}: batched Cholesky left beside the kernel")
     say(f"{SMOKE} {label}: lower+compile {compile_s:.3f} s, "
         f"block program needs {compiled_hbm_bytes(compiled) / 2**30:.3f} GiB")
     impls = sorted({dec.impl for _, _, dec in decisions})
-    return f"{label}: {len(decisions)} Gram dispatches -> {'+'.join(impls)}; tpu_custom_call={kernels}"
+    return (f"{label}: {len(decisions)} Gram dispatches -> {'+'.join(impls)}; tpu_custom_call={kernels}; "
+            f"draws on kernel {on_kernel}/{len(draws)}")
 
 
 def run_blocks(engine, label: str, stop_after: int | None = None) -> None:

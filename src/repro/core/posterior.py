@@ -9,7 +9,8 @@ centered ratings {r_ij}:
 
 The paper's multi-core contribution is making the "for all items" loop fast
 under skewed nnz; here each nnz-bucket is one dense [B, P, K] gather plus a
-Gram contraction (Pallas kernel on TPU), and the Cholesky solve is batched.
+Gram contraction (Pallas kernel on TPU), and the Cholesky solve is batched
+(``kernels.ops.posterior_draw``: items on the vector lanes on TPU).
 
 Noise is generated per *global item id* with ``jax.random.fold_in`` so every
 layout (single device, ring-distributed, re-balanced) produces the same
@@ -82,18 +83,19 @@ def sample_from_terms(
     g: jax.Array,
     hyper: HyperParams,
 ) -> jax.Array:
-    """Draw x_i ~ N(P^-1 l, P^-1) for a batch of items from accumulated terms."""
+    """Draw x_i ~ N(P^-1 l, P^-1) for a batch of items from accumulated terms.
+
+    P = G + Lambda and l = g + Lambda mu; the factor and solves dispatch
+    through ``kernels.ops.posterior_draw``. The noise is drawn per global
+    item id here, so the sample does not depend on the implementation's
+    layout.
+    """
+    from repro.kernels import ops as kops
+
     K = g.shape[-1]
-    prec = G + hyper.Lam  # [B, K, K]
     lam_mu = jnp.matmul(hyper.Lam, hyper.mu, precision=jax.lax.Precision.HIGHEST)
-    lin = g + lam_mu  # [B, K] (broadcast add of [K])
-    L = jnp.linalg.cholesky(prec)
-    # mean = P^-1 lin via two triangular solves
-    y = solve_triangular(L, lin[..., None], lower=True)
-    mean = solve_triangular(jnp.swapaxes(L, -1, -2), y, lower=False)[..., 0]
     z = item_noise(key, item_ids, K, dtype=g.dtype)
-    noise = solve_triangular(jnp.swapaxes(L, -1, -2), z[..., None], lower=False)[..., 0]
-    return mean + noise
+    return kops.posterior_draw(G, g, hyper.Lam, lam_mu, z)
 
 
 def update_bucket(

@@ -14,6 +14,11 @@ warmed autotune cache (or an explicit ``gram_impl``) selects a kernel. An
 explicit kernel whose working set cannot be tiled raises instead of
 silently running XLA; :func:`record_gram_decisions` lists what a trace
 resolved to.
+
+:func:`posterior_draw` dispatches the per-item draw the same way: the
+lane-batched Cholesky kernel (``chol_draw.py``) on the TPU when the rank
+fits its VMEM budget, XLA's batched Cholesky and triangular solves
+otherwise; :func:`record_draw_decisions` lists what a trace resolved to.
 """
 from __future__ import annotations
 
@@ -22,17 +27,22 @@ import contextvars
 
 import jax
 import jax.numpy as jnp
+from jax.scipy.linalg import solve_triangular
 
-from repro.core.types import GRAM_SCOPE
-from repro.kernels import autotune, ref
+from repro.core.types import DRAW_SCOPE, GRAM_SCOPE
+from repro.kernels import autotune, chol_draw, ref
 from repro.kernels.bpmf_gram import (
     bpmf_gram_fused, bpmf_gram_pallas, mxu_precision, vmem_bytes_estimate,
 )
 from repro.utils import round_up
 
-# the list record_gram_decisions() yields, while its block is open
+# the lists record_gram_decisions() and record_draw_decisions() yield,
+# while their blocks are open
 _DECISIONS: contextvars.ContextVar[list | None] = contextvars.ContextVar(
     "gram_decisions", default=None
+)
+_DRAW_DECISIONS: contextvars.ContextVar[list | None] = contextvars.ContextVar(
+    "draw_decisions", default=None
 )
 
 
@@ -58,8 +68,24 @@ def record_gram_decisions():
         _DECISIONS.reset(token)
 
 
+@contextlib.contextmanager
+def record_draw_decisions():
+    """Collect every posterior-draw dispatch decision traced inside the block.
+
+    Yields a list that fills with ``("draw", (B, K), Decision)`` tuples, one
+    per traced :func:`posterior_draw`; a kernel decision carries its block
+    of items in ``tb``. Only tracing records.
+    """
+    decisions: list = []
+    token = _DRAW_DECISIONS.set(decisions)
+    try:
+        yield decisions
+    finally:
+        _DRAW_DECISIONS.reset(token)
+
+
 def _record(kind: str, shape: tuple, dec: autotune.Decision) -> None:
-    decisions = _DECISIONS.get()
+    decisions = (_DRAW_DECISIONS if kind == "draw" else _DECISIONS).get()
     if decisions is not None:
         decisions.append((kind, shape, dec))
 
@@ -353,3 +379,68 @@ def bpmf_gram_step(
         # commute and the sums are unchanged
         (G, g), _ = jax.lax.scan(add, (G, g), b.row_tiles())
     return G, g
+
+
+def draw_decision(B: int, K: int, backend: str | None = None) -> autotune.Decision:
+    """``auto`` for the posterior draw: the kernel on the TPU when the rank
+    fits its VMEM budget and the batch is not empty, XLA otherwise."""
+    bt = chol_draw.block_items(K)
+    if (backend or jax.default_backend()) == "tpu" and bt is not None and B > 0:
+        return autotune.Decision("pallas", tb=bt)
+    return autotune.Decision("xla")
+
+
+def _posterior_draw_xla(prec: jax.Array, lin: jax.Array, z: jax.Array) -> jax.Array:
+    """XLA's batched Cholesky and three triangular solves."""
+    L = jnp.linalg.cholesky(prec)
+    # mean = P^-1 lin via two triangular solves
+    y = solve_triangular(L, lin[..., None], lower=True)
+    mean = solve_triangular(jnp.swapaxes(L, -1, -2), y, lower=False)[..., 0]
+    noise = solve_triangular(jnp.swapaxes(L, -1, -2), z[..., None], lower=False)[..., 0]
+    return mean + noise
+
+
+@jax.named_scope(DRAW_SCOPE)
+def posterior_draw(
+    G: jax.Array,
+    g: jax.Array,
+    Lam: jax.Array,
+    lam_mu: jax.Array,
+    z: jax.Array,
+    *,
+    impl: str = "auto",
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Draw ``x = P^-1 l + chol(P)^-T z`` per item; returns ``[B, K]``.
+
+    ``P = G + Lam`` (``G [B, K, K]``) and ``l = g + lam_mu`` (``g [B, K]``).
+    ``impl`` is ``"auto"`` (:func:`draw_decision`), ``"pallas"`` (the
+    lane-batched kernel) or ``"xla"``. Both factor ``P`` as
+    ``jnp.linalg.cholesky`` does, symmetrized, so they draw the same
+    numbers up to rounding.
+
+    Raises:
+        ValueError: ``impl="pallas"`` at a rank whose working set does not
+            fit the kernel's VMEM budget.
+    """
+    B, K = g.shape
+    if interpret is None:
+        interpret = pallas_interpret()
+    if impl == "auto":
+        dec = draw_decision(B, K)
+    elif impl == "pallas":
+        bt = chol_draw.block_items(K)
+        if bt is None:
+            raise ValueError(f"draw kernel does not fit the VMEM budget at K={K}; use 'xla'")
+        dec = autotune.Decision("pallas", tb=bt)
+    elif impl == "xla":
+        dec = autotune.Decision("xla")
+    else:
+        raise ValueError(f"unknown impl {impl!r}; one of auto|pallas|xla")
+    _record("draw", (B, K), dec)
+    prec = G + Lam
+    lin = g + lam_mu
+    if dec.impl == "xla":
+        return _posterior_draw_xla(prec, lin, z)
+    sym = (prec + jnp.swapaxes(prec, -1, -2)) / 2  # jnp.linalg.cholesky's symmetrize
+    return chol_draw.chol_draw(sym, lin, z, bt=dec.tb, interpret=interpret)

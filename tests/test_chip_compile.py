@@ -1,10 +1,11 @@
-"""Compile the Gram kernels and the one-chip sweep block for a described v5e.
+"""Compile the Gram and draw kernels and the one-chip sweep block for a described v5e.
 
 Nothing runs here. The TPU compiler that ships with jax compiles for a
 topology that is described, not attached, and refuses what the chip would
 refuse: Pallas block shapes, VMEM overflow, a program larger than HBM. Code
 that asks ``jax.default_backend()`` still sees the CPU, so every Gram
-implementation is passed explicitly.
+implementation is passed explicitly, and the draw's ``auto`` is steered to
+its TPU decision by the test.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and a worker that imports this
@@ -14,6 +15,7 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,7 +26,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import gibbs
 from repro.core.prediction import PredictionState
 from repro.core.types import BPMFConfig, Bucket, PosteriorAccum, gram_tile_rows
-from repro.kernels import autotune, ops
+from repro.kernels import autotune, chol_draw, ops
 from repro.kernels.bpmf_gram import bpmf_gram_fused, bpmf_gram_pallas
 from repro.utils import compiled_hbm_bytes, round_up
 
@@ -152,11 +154,35 @@ def _chembl_block(one_chip):
     return coo, data, cfg, [_abstract(a, one_chip) for a in args]
 
 
-def test_sequential_block_fits_one_chip_at_chembl(one_chip):
-    """The ChEMBL K=32 sweep block leaves >= 2 GiB of one v5e's HBM free.
+@pytest.mark.parametrize(
+    "B,K", [(gram_tile_rows(8), K), (RING_CAP_U, K), (1, K), (4096, 100)],
+    ids=["chembl_tile", "ring_shard", "one_item", "k100"],
+)
+def test_draw_kernel_compiles(one_chip, B, K):
+    """The lane-batched draw compiles at a ChEMBL row tile, a four-chip ring
+    shard, a single item and the candidate K=100, at the block of items
+    ``auto`` picks on the TPU."""
+    dec = ops.draw_decision(B, K, backend="tpu")
+    assert dec.impl == "pallas"
+    s = functools.partial(_sds, one_chip)
+    lowered = chol_draw.chol_draw.lower(
+        s((B, K, K), jnp.float32), s((B, K), jnp.float32), s((B, K), jnp.float32),
+        bt=dec.tb, interpret=False,
+    )
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+CUSTOM_CALL = re.compile(r"= (\S+) custom-call\(.*custom_call_target=\"(\w+)\"")
+
+
+def test_sequential_block_fits_one_chip_at_chembl(one_chip, monkeypatch):
+    """The ChEMBL K=32 sweep block leaves >= 2 GiB of one v5e's HBM free,
+    with every per-item draw on the lane-batched kernel.
 
     ``auto`` resolves every bucket of this shape to XLA on the TPU (checked
     here on TPU keys), so the block is compiled with that impl explicitly.
+    The draw's ``auto`` is given its TPU decision. The only Cholesky left
+    is the hyper draw's single K x K one per side.
     """
     coo, data, cfg, args = _chembl_block(one_chip)
     for side, Ns in ((data.users, coo.num_movies), (data.movies, coo.num_users)):
@@ -164,7 +190,19 @@ def test_sequential_block_fits_one_chip_at_chembl(one_chip):
             rows = min(b.B, gram_tile_rows(b.P))
             dec = autotune.heuristic(autotune.bucket_key(rows, b.P, Ns, K, backend="tpu"))
             assert dec.impl == "xla", (b.B, b.P, dec)
-    compiled = gibbs.gibbs_sweep_block_donated.lower(*args, cfg, 8).compile()
+    monkeypatch.setattr(ops, "draw_decision", functools.partial(ops.draw_decision, backend="tpu"))
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    with ops.record_draw_decisions() as draws:
+        lowered = gibbs.gibbs_sweep_block_donated.lower(*args, cfg, 8)
+    compiled = lowered.compile()
+    assert draws and {dec.impl for _, _, dec in draws} == {"pallas"}
+    text = compiled.as_text()
+    calls = [(m.group(1), m.group(2), line) for line in text.splitlines()
+             if (m := CUSTOM_CALL.search(line))]
+    kernels = [line for shape, target, line in calls if target == "tpu_custom_call"]
+    assert kernels and all("posterior_draw" in line for line in kernels)
+    cholesky = [shape for shape, target, _ in calls if target == "Cholesky"]
+    assert all(re.fullmatch(rf"f32\[{K},{K}\]\S*", shape) for shape in cholesky), cholesky
     need = compiled_hbm_bytes(compiled)
     assert need + HEADROOM <= V5E_HBM, f"{need / 2**30:.2f} GiB of {V5E_HBM / 2**30} GiB"
     assert np.isfinite(need) and need > 0

@@ -8,6 +8,7 @@ slots as they are laid out.
 from __future__ import annotations
 
 import collections
+import functools
 import glob
 import json
 import os
@@ -64,6 +65,48 @@ def test_sequential_block_names_its_layers():
     # every gather and contraction of the sweep sits under some layer
     for prim in ("gather", "dot_general"):
         assert None not in by_prim[prim], prim
+
+
+def pallas_call_paths(jaxpr) -> list[str]:
+    """The full name-stack path of every ``pallas_call`` in a closed jaxpr,
+    as it becomes the kernel custom-call's ``op_name``."""
+    out = []
+
+    def walk(jx, prefix):
+        for eqn in jx.eqns:
+            path = "/".join(p for p in (prefix, str(eqn.source_info.name_stack)) if p)
+            if eqn.primitive.name == "pallas_call":
+                out.append(f"{path}/pallas_call")
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, path)
+
+    walk(jaxpr.jaxpr, "")
+    return out
+
+
+def test_draw_kernel_is_named_posterior_draw(monkeypatch):
+    """On the TPU the per-item draw is one Pallas kernel; its custom-call
+    carries ``posterior_draw`` as its innermost layer, so a trace's split
+    gives its time to the draw and not to ``unattributed``."""
+    from repro.core import gibbs
+    from repro.core.prediction import PredictionState
+    from repro.data.sparse import build_bpmf_data
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "draw_decision", functools.partial(ops.draw_decision, backend="tpu"))
+    coo = load_dataset("synthetic", num_users=60, num_movies=40, nnz=600)
+    data = build_bpmf_data(coo)
+    cfg = types.BPMFConfig(K=4)
+    state = gibbs.init_state(jax.random.key(0), coo.num_users, coo.num_movies, cfg)
+    pred = PredictionState.init(int(data.test.rows.shape[0]))
+    with ops.record_draw_decisions() as draws:
+        jaxpr = jax.make_jaxpr(gibbs.gibbs_sweep, static_argnums=4)(
+            jax.random.key(1), state, pred, data, cfg)
+    assert draws and {dec.impl for _, _, dec in draws} == {"pallas"}
+    paths = pallas_call_paths(jaxpr)
+    assert len(paths) == len(draws)
+    assert {innermost(p) for p in paths} == {"posterior_draw"}, paths
 
 
 RING_CODE = """
