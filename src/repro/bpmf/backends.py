@@ -259,6 +259,8 @@ class Backend(abc.ABC):
     #: Approximate-inference backends (``posterior_merge``) set it False
     #: and are gated by the statistical harness instead.
     exact_parity: bool = True
+    #: The model families (``ModelConfig.family``) the backend can run.
+    families: tuple[str, ...] = ("bpmf",)
 
     def __init__(self, cfg: BPMFConfig):
         self.cfg = cfg
@@ -414,11 +416,20 @@ class Backend(abc.ABC):
 
 @register_backend("sequential")
 class SequentialBackend(Backend):
-    """Single-program Algorithm 1 via :mod:`repro.core.gibbs`."""
+    """Single-program Algorithm 1 via :mod:`repro.core.gibbs`; also the
+    tensor model (BPTF), whose data carries a time side."""
+
+    families = ("bpmf", "bptf")
 
     def prepare(self, coo: RatingsCOO | ChunkedRatings) -> None:
         if isinstance(coo, ChunkedRatings):  # no per-host path: concatenate
             coo = coo.materialize()
+        if self.cfg.model.family == "bptf":
+            if coo.times is None:
+                raise ValueError("model family 'bptf' needs ratings with time bins "
+                                 "(RatingsCOO.times)")
+        else:
+            coo = coo.without_times()
         self.data = build_bpmf_data(
             coo,
             pads=self.cfg.backend.bucket_pads,
@@ -427,10 +438,13 @@ class SequentialBackend(Backend):
         )
         self._layout = {"users": self.data.users.layout_stats(),
                         "movies": self.data.movies.layout_stats()}
+        if self.data.times is not None:
+            self._layout["times"] = self.data.times.layout_stats()
         self._prepared = True
 
     def init_state(self, key: jax.Array):
-        return gibbs.init_state(key, self.data.num_users, self.data.num_movies, self.core_cfg)
+        return gibbs.init_state(key, self.data.num_users, self.data.num_movies, self.core_cfg,
+                                self.data.num_times)
 
     def sweep(self, key: jax.Array, state, pred: PredictionState):
         return gibbs.gibbs_sweep(key, state, pred, self.data, self.core_cfg)

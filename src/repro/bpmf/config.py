@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from repro.core import types as core_types
 
 _GRAM_IMPLS = ("auto", "pallas_fused", "pallas", "xla")
+MODEL_FAMILIES = ("bpmf", "bptf")
 _USE_PALLAS_WARNED = False
 
 
@@ -48,6 +49,10 @@ class ModelConfig:
     """The BPMF model itself (paper §III): rank, noise and prior.
 
     Attributes:
+        family: ``"bpmf"``, the two-sided matrix model, or ``"bptf"``, the
+            temporal tensor model ``R_ijk ~ <U_i, V_j, T_k>`` of Xiong et al.
+            (SDM 2010), whose time factors follow a Markov chain; it needs
+            ratings with time bins and runs on the ``sequential`` backend.
         K: Latent rank of the factorization ``R ~ U @ V.T``.
         alpha: Rating noise precision (likelihood ``N(r | u·v, 1/alpha)``).
         beta0: Normal-Wishart prior strength on the factor means.
@@ -56,11 +61,18 @@ class ModelConfig:
             default) or bf16; accumulation is f32 either way.
     """
 
+    family: str = "bpmf"  # bpmf | bptf
     K: int = 32
     alpha: float = 2.0  # rating noise precision
     beta0: float = 2.0  # Normal-Wishart prior strength
     sample_dtype: Any = jnp.float32
     compute_dtype: Any = jnp.float32  # Gram contraction dtype (f32 or bf16)
+
+    def __post_init__(self) -> None:
+        if self.family not in MODEL_FAMILIES:
+            raise ValueError(
+                f"ModelConfig.family must be one of {MODEL_FAMILIES}, got {self.family!r}"
+            )
 
 
 @dataclasses.dataclass(frozen=True)

@@ -10,7 +10,10 @@ New workloads register here instead of adding another ad-hoc script::
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Callable
+
+import numpy as np
 
 from repro.data.movielens import load_chembl, load_movielens
 from repro.data.sparse import RatingsCOO
@@ -70,8 +73,10 @@ def _synthetic(
     noise_std: float = 0.5,
     discretize: bool = False,
     seed: int = 0,
+    num_times: int = 0,
 ) -> RatingsCOO:
-    """Low-rank + noise ratings with MovieLens-shaped degree skew."""
+    """Low-rank + noise ratings with MovieLens-shaped degree skew; with
+    ``num_times`` > 0, each rating gets a uniform time bin."""
     spec = SyntheticSpec(
         num_users=num_users,
         num_movies=num_movies,
@@ -82,13 +87,18 @@ def _synthetic(
         seed=seed,
     )
     coo, _ = synthetic_ratings(spec)
+    if num_times:
+        times = np.random.default_rng([seed, 1]).integers(0, num_times, coo.nnz)
+        coo = dataclasses.replace(coo, times=times.astype(np.int32), num_times=num_times)
     return coo
 
 
 @register_dataset("movielens")
-def _movielens(path: str | None = None, variant: str = "ml-100k") -> RatingsCOO:
-    """Real ml-20m/ml-100k files when ``path`` exists, else synthetic stand-in."""
-    return load_movielens(path, variant)
+def _movielens(path: str | None = None, variant: str = "ml-100k",
+               times: bool = False) -> RatingsCOO:
+    """Real ml-20m/ml-100k files when ``path`` exists, else synthetic stand-in;
+    ``times`` keeps each rating's calendar month (a real file only)."""
+    return load_movielens(path, variant, times=times)
 
 
 @register_dataset("chembl")

@@ -132,6 +132,12 @@ class BPMFEngine:
         Returns:
             ``self``, prepared.
         """
+        family = self.cfg.model.family
+        if family not in self.backend.families:
+            raise ValueError(
+                f"backend {self.backend.name!r} runs the model families "
+                f"{self.backend.families}, not {family!r}; use backend 'sequential'"
+            )
         fingerprint = (data.num_users, data.num_movies, data.nnz)
         if self.backend.prepared:
             if fingerprint != self._data_fingerprint:
@@ -388,7 +394,16 @@ class BPMFEngine:
         (one host gather per device accumulator; the ``posterior_merge``
         backend additionally runs its subset-posterior merge here — its
         only communication event).
+
+        Raises:
+            ValueError: For the tensor model (``family="bptf"``), whose
+                predictions need the time factors the artifact cannot hold.
         """
+        if self.cfg.model.family != "bpmf":
+            raise ValueError(
+                f"serving exports the matrix model only; model family "
+                f"{self.cfg.model.family!r} cannot be exported or served"
+            )
         self._ensure_state()
         summary = self.backend.posterior_export(self._accum)
         count = int(summary["count"])

@@ -7,6 +7,11 @@ Order per sweep (exactly Algorithm 1):
   4. resample every user from (new V, R)
   5. predict test points, update RMSE
 
+The tensor model (BPTF, data with a time side) adds, before the
+predictions, the time chain's hyper-parameters from T and the time factors
+in order of their bins (``posterior.update_time_chain``); users and movies
+then see ``V_j * T_k`` and ``U_i * T_k`` as their neighbours.
+
 The distributed sampler in ``core/distributed.py`` reuses the same
 sub-routines under ``shard_map``; this module is the sequential oracle.
 """
@@ -19,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import posterior
-from repro.core.hyper import sample_hyper
+from repro.core.hyper import sample_hyper, sample_hyper_chain
 from repro.core.prediction import (
     PredictionState,
     update_posterior_accum,
@@ -55,27 +60,34 @@ def init_rows(key: jax.Array, ids: jax.Array, K: int, dtype=jnp.float32) -> jax.
     return jax.vmap(one)(ids)
 
 
-def init_state(key: jax.Array, num_users: int, num_movies: int, cfg: BPMFConfig) -> BPMFState:
-    """Draw U, V from the prior predictive (standard normal scaled)."""
+def init_state(key: jax.Array, num_users: int, num_movies: int, cfg: BPMFConfig,
+               num_times: int = 0) -> BPMFState:
+    """Draw U, V from the prior predictive (standard normal scaled); with
+    ``num_times`` bins, T starts at ones, so the first half-sweeps see
+    BPMF's Gram terms."""
     ku, kv = jax.random.split(key)
     dt = cfg.sample_dtype
+    tensor = num_times > 0
     return BPMFState(
         U=init_rows(ku, jnp.arange(num_users, dtype=jnp.int32), cfg.K, dt),
         V=init_rows(kv, jnp.arange(num_movies, dtype=jnp.int32), cfg.K, dt),
         hyper_U=HyperParams.init(cfg.K, dt),
         hyper_V=HyperParams.init(cfg.K, dt),
         sweep=jnp.zeros((), jnp.int32),
+        T=jnp.ones((num_times, cfg.K), dt) if tensor else None,
+        hyper_T=HyperParams.init(cfg.K, dt) if tensor else None,
     )
 
 
-def sweep_keys(key: jax.Array, sweep: jax.Array) -> tuple[jax.Array, ...]:
-    """Deterministic per-sweep keys: (hyper_V, movies, hyper_U, users).
+def sweep_keys(key: jax.Array, sweep: jax.Array, n: int = 4) -> tuple[jax.Array, ...]:
+    """Deterministic per-sweep keys: (hyper_V, movies, hyper_U, users), and
+    with ``n=6`` the time chain's (hyper_T, times).
 
     Keys depend only on (base key, sweep index) so any layout of the sampler
     draws identical randomness.
     """
     k = jax.random.fold_in(key, sweep)
-    return tuple(jax.random.fold_in(k, i) for i in range(4))
+    return tuple(jax.random.fold_in(k, i) for i in range(n))
 
 
 def _sweep_body(
@@ -88,25 +100,35 @@ def _sweep_body(
     """One Gibbs sweep (Algorithm 1), traceable — shared by the per-sweep
     jit entry point and the blocked ``lax.scan`` loop."""
     prior = cfg.prior()
-    k_hv, k_v, k_hu, k_u = sweep_keys(key, state.sweep)
+    T, hyper_T = state.T, state.hyper_T
+    tensor = data.times is not None
+    keys = sweep_keys(key, state.sweep, 6 if tensor else 4)
+    k_hv, k_v, k_hu, k_u = keys[:4]
 
-    # movies given users
+    # movies given users (and times)
     hyper_V = sample_hyper(k_hv, state.V, prior)
     V = posterior.update_side(
         k_v, state.V, state.U, data.movies, hyper_V, cfg.alpha,
-        cfg.compute_dtype, cfg.gram_impl,
+        cfg.compute_dtype, cfg.gram_impl, T,
     )
-    # users given (updated) movies
+    # users given (updated) movies (and times)
     hyper_U = sample_hyper(k_hu, state.U, prior)
     U = posterior.update_side(
         k_u, state.U, V, data.users, hyper_U, cfg.alpha,
-        cfg.compute_dtype, cfg.gram_impl,
+        cfg.compute_dtype, cfg.gram_impl, T,
     )
+    if tensor:  # the time chain given (updated) users and movies
+        k_ht, k_t = keys[4:]
+        hyper_T = sample_hyper_chain(k_ht, T, prior)
+        T = posterior.update_time_chain(
+            k_t, T, U, V, data.times, hyper_T, cfg.alpha, cfg.compute_dtype, cfg.gram_impl,
+        )
 
     sweep = state.sweep + 1
-    new_state = BPMFState(U=U, V=V, hyper_U=hyper_U, hyper_V=hyper_V, sweep=sweep)
+    new_state = BPMFState(U=U, V=V, hyper_U=hyper_U, hyper_V=hyper_V, sweep=sweep,
+                          T=T, hyper_T=hyper_T)
     pred_state, r_sample, r_avg = update_predictions(
-        pred_state, U, V, data, burned_in=sweep > cfg.burn_in
+        pred_state, U, V, data, burned_in=sweep > cfg.burn_in, T=T
     )
     return new_state, pred_state, SweepMetrics(r_sample, r_avg, sweep)
 

@@ -77,8 +77,6 @@ def sample_hyper_from_stats(
     prior: NormalWishartPrior,
 ) -> HyperParams:
     """Sample (mu, Lambda) from the NW conditional given sufficient stats."""
-    dtype = sum_x.dtype
-    K = sum_x.shape[-1]
     xbar = sum_x / n
     S = sum_xxT / n - jnp.outer(xbar, xbar)
     S = 0.5 * (S + S.T)
@@ -89,6 +87,19 @@ def sample_hyper_from_stats(
     dm = prior.mu0 - xbar
     W0_inv = jnp.linalg.inv(prior.W0)
     Wstar_inv = W0_inv + n * S + (prior.beta0 * n / beta_star) * jnp.outer(dm, dm)
+    return _sample_normal_wishart(key, mu_star, beta_star, nu_star, Wstar_inv)
+
+
+def _sample_normal_wishart(
+    key: jax.Array,
+    mu_star: jax.Array,
+    beta_star: jax.Array,
+    nu_star: jax.Array,
+    Wstar_inv: jax.Array,
+) -> HyperParams:
+    """(mu, Lambda) ~ NW(mu*, beta*, W*, nu*), given the inverse scale."""
+    dtype = mu_star.dtype
+    K = mu_star.shape[-1]
     Wstar_inv = 0.5 * (Wstar_inv + Wstar_inv.T)
     Wstar = jnp.linalg.inv(Wstar_inv)
     Wstar = 0.5 * (Wstar + Wstar.T)
@@ -115,3 +126,26 @@ def sample_hyper(
     """Sample (mu, Lambda) from the NW conditional given latent rows X."""
     n, sx, sxx = hyper_sufficient_stats(X, weights)
     return sample_hyper_from_stats(key, n, sx, sxx, prior)
+
+
+@jax.named_scope(HYPER_SCOPE)
+def sample_hyper_chain(key: jax.Array, T: jax.Array, prior: NormalWishartPrior) -> HyperParams:
+    """Sample ``(mu_T, Lambda_T)`` of the time chain ``T_1 ~ N(mu_T,
+    Lambda_T^-1)``, ``T_k ~ N(T_{k-1}, Lambda_T^-1)`` given its rows
+    (Xiong et al. 2010, section 4)::
+
+        beta* = beta0 + 1               nu* = nu0 + K_T
+        mu*   = (beta0 mu0 + T_1) / (beta0 + 1)
+        W*^-1 = W0^-1 + sum_{k>=2} d_k d_k^T + beta0 / (beta0 + 1) (T_1 - mu0)(T_1 - mu0)^T
+
+    with ``d_k = T_k - T_{k-1}``; the draw is the one
+    :func:`sample_hyper_from_stats` makes.
+    """
+    n = T.shape[0]
+    D = T[1:] - T[:-1]
+    d0 = T[0] - prior.mu0
+    beta_star = prior.beta0 + 1.0
+    mu_star = (prior.beta0 * prior.mu0 + T[0]) / beta_star
+    Wstar_inv = (jnp.linalg.inv(prior.W0) + jnp.matmul(D.T, D, precision=_F32)
+                 + (prior.beta0 / beta_star) * jnp.outer(d0, d0))
+    return _sample_normal_wishart(key, mu_star, beta_star, prior.nu0 + n, Wstar_inv)

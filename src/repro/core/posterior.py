@@ -16,6 +16,10 @@ Noise is generated per *global item id* with ``jax.random.fold_in`` so every
 layout (single device, ring-distributed, re-balanced) produces the same
 sample for the same item — the cross-version RMSE-parity claim of the paper
 (§V-B) becomes an exact test instead of a statistical one.
+
+In the tensor model (BPTF) the neighbour vector of a rating is the Hadamard
+product of the rows of its two other sides, ``x = V_j * T_k`` for a user,
+and the time factors are drawn as a chain (:func:`update_time_chain`).
 """
 from __future__ import annotations
 
@@ -25,7 +29,11 @@ import jax
 import jax.numpy as jnp
 from jax.scipy.linalg import solve_triangular
 
-from repro.core.types import DRAW_SCOPE, GRAM_SCOPE, Bucket, BucketedSide, HyperParams
+from repro.core.types import (
+    DRAW_SCOPE, GRAM_SCOPE, TIME_CHAIN_SCOPE, Bucket, BucketedSide, HyperParams,
+)
+
+_F32 = jax.lax.Precision.HIGHEST
 
 
 @jax.named_scope(DRAW_SCOPE)
@@ -52,8 +60,12 @@ def gram_terms(
     alpha: float,
     compute_dtype=jnp.float32,
     gram_impl: str | bool = "xla",
+    X_opp2: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """(G, g) with G = alpha * sum_j x_j x_j^T  [B,K,K], g = alpha * sum_j x_j r_j [B,K].
+
+    ``x_j`` is the neighbour's row of ``X_opp``, times its row of ``X_opp2``
+    (at ``bucket.nbr2``) when the bucket is three-way.
 
     ``gram_impl`` selects the gather+Gram implementation — ``"auto"``
     (autotune cache → heuristic), ``"pallas"`` or ``"xla"``; a legacy
@@ -70,6 +82,7 @@ def gram_terms(
         X_opp, bucket.nbr, bucket.val, bucket.nnz,
         compute_dtype=compute_dtype,
         impl="pallas" if gram_impl == "pallas_fused" else gram_impl,
+        X2=None if bucket.nbr2 is None else X_opp2, nbr2=bucket.nbr2,
     )
     a = jnp.asarray(alpha, jnp.float32)
     return a * G, a * g
@@ -107,6 +120,7 @@ def update_bucket(
     alpha: float,
     compute_dtype=jnp.float32,
     gram_impl: str | bool = "xla",
+    X_opp2: jax.Array | None = None,
 ) -> jax.Array:
     """Sample all items of one bucket and scatter them into X_side.
 
@@ -118,7 +132,7 @@ def update_bucket(
     """
 
     def draw(b: Bucket) -> jax.Array:
-        G, g = gram_terms(X_opp, b, alpha, compute_dtype, gram_impl)
+        G, g = gram_terms(X_opp, b, alpha, compute_dtype, gram_impl, X_opp2)
         return sample_from_terms(key, b.item_ids, G, g, hyper)
 
     tiled = jax.lax.map(draw, bucket.row_tiles())
@@ -136,8 +150,10 @@ def update_side(
     alpha: float,
     compute_dtype=jnp.float32,
     gram_impl: str | bool = "xla",
+    X_opp2: jax.Array | None = None,
 ) -> jax.Array:
-    """One half-sweep: resample every item of X_side given X_opp.
+    """One half-sweep: resample every item of X_side given X_opp (and, for
+    a three-way side, ``X_opp2``).
 
     Items are conditionally independent given (X_opp, hyper), so bucket order
     does not matter statistically; we loop buckets smallest-P first (the
@@ -145,9 +161,65 @@ def update_side(
     """
     for bucket in side.buckets:
         X_side = update_bucket(
-            key, X_side, X_opp, bucket, hyper, alpha, compute_dtype, gram_impl
+            key, X_side, X_opp, bucket, hyper, alpha, compute_dtype, gram_impl, X_opp2
         )
     return X_side
+
+
+def update_time_chain(
+    key: jax.Array,
+    T: jax.Array,
+    U: jax.Array,
+    V: jax.Array,
+    side: BucketedSide,
+    hyper: HyperParams,
+    alpha: float,
+    compute_dtype=jnp.float32,
+    gram_impl: str | bool = "xla",
+) -> jax.Array:
+    """Draw the time factors ``T [K_T, K]`` in order, given U, V and the
+    chain's ``(mu_T, Lam_T)`` (Xiong et al. 2010, section 4).
+
+    Under the prior ``T_1 ~ N(mu_T, Lam_T^-1)``, ``T_k ~ N(T_{k-1},
+    Lam_T^-1)`` the bins are not conditionally independent: bin ``k``'s
+    conditional has precision ``Lam_T (1 + [k < K_T]) + G_k`` and linear term
+    ``Lam_T p_k + [k < K_T] Lam_T T_{k+1} + g_k``, with ``p_1 = mu_T`` and
+    ``p_k`` the *new* ``T_{k-1}``; ``(G_k, g_k)`` are the Gram terms of
+    ``x = U_i * V_j`` over the bin's ratings. The Gram terms of every bin
+    come first, bucketed as the other sides are; only the ``K x K`` draws
+    run as a ``lax.scan`` over the bins, bin ``k`` with the noise
+    ``normal(fold_in(key, k))``.
+    """
+    n, K = T.shape
+    with jax.named_scope(GRAM_SCOPE):
+        G = jnp.zeros((n, K, K), jnp.float32)
+        g = jnp.zeros((n, K), jnp.float32)
+        for bucket in side.buckets:
+            Gb, gb = jax.lax.map(
+                lambda b: gram_terms(U, b, alpha, compute_dtype, gram_impl, V),
+                bucket.row_tiles())
+            # dead rows of the last tile are sliced off: their id -1 would wrap
+            G = G.at[bucket.item_ids].set(Gb.reshape(-1, K, K)[: bucket.B], mode="drop")
+            g = g.at[bucket.item_ids].set(gb.reshape(-1, K)[: bucket.B], mode="drop")
+    with jax.named_scope(TIME_CHAIN_SCOPE):
+        from repro.kernels import ops as kops
+
+        Lam = hyper.Lam
+        after = (jnp.arange(n) < n - 1).astype(jnp.float32)  # [k < K_T]
+        T_next = jnp.concatenate([T[1:], jnp.zeros((1, K), T.dtype)]).astype(jnp.float32)
+        lam_next = jnp.matmul(T_next, Lam.T, precision=_F32)  # Lam_T T_{k+1}, old T
+        z = item_noise(key, jnp.arange(n, dtype=jnp.int32), K)
+
+        def draw(prev, xs):
+            Gk, gk, ak, lk, zk = xs
+            lam_prev = jnp.matmul(Lam, prev, precision=_F32)
+            x = kops.posterior_draw((Gk + ak * Lam)[None], (gk + ak * lk)[None], Lam,
+                                    lam_prev, zk[None])[0]
+            return x, x
+
+        _, T_new = jax.lax.scan(draw, hyper.mu.astype(jnp.float32),
+                                (G, g, after, lam_next, z))
+    return T_new.astype(T.dtype)
 
 
 # --- reference (naive, un-bucketed) implementation for testing -----------------

@@ -24,9 +24,13 @@ class PredictionState:
 
 
 def predict(U: jax.Array, V: jax.Array, test: TestSet, mean_rating: jax.Array,
-            min_rating: float, max_rating: float) -> jax.Array:
-    """Point predictions for the test triples from one posterior sample."""
-    preds = jnp.sum(U[test.rows] * V[test.cols], axis=-1) + mean_rating
+            min_rating: float, max_rating: float, T: jax.Array | None = None) -> jax.Array:
+    """Point predictions for the test triples from one posterior sample:
+    ``<u_i, v_j>``, or ``<u_i, v_j, t_k>`` with time factors ``T``."""
+    prod = U[test.rows] * V[test.cols]
+    if T is not None:
+        prod = prod * T[test.times]
+    preds = jnp.sum(prod, axis=-1) + mean_rating
     return jnp.clip(preds, min_rating, max_rating)
 
 
@@ -41,9 +45,10 @@ def update_predictions(
     V: jax.Array,
     data: BPMFData,
     burned_in: jax.Array,
+    T: jax.Array | None = None,
 ) -> tuple[PredictionState, jax.Array, jax.Array]:
     """Accumulate posterior mean after burn-in; return (state, rmse_sample, rmse_avg)."""
-    preds = predict(U, V, data.test, data.mean_rating, data.min_rating, data.max_rating)
+    preds = predict(U, V, data.test, data.mean_rating, data.min_rating, data.max_rating, T)
     r_sample = rmse(preds, data.test.vals)
     inc = burned_in.astype(jnp.int32)
     new_state = PredictionState(

@@ -5,6 +5,10 @@ The rating matrix ``R`` (M users x N movies, sparse) is factorized as
 independence of items given the opposite factor matrix is the source of all
 parallelism in the paper; the containers here encode the bucketed layout that
 makes that parallelism dense enough for the MXU.
+
+The tensor model (BPTF, Xiong et al. 2010) adds a third side of ``K_T``
+time bins, ``R_ijk ~ sum_d U_id V_jd T_kd``: the state then holds ``T`` and
+its hyper-parameters, and every side's buckets hold two neighbour indices.
 """
 from __future__ import annotations
 
@@ -31,6 +35,12 @@ GRAM_TILE_SLOTS = 1 << 16
 #: the scopes it was traced under, so a profiler trace can be split by layer.
 SWEEP_SCOPES = ("bpmf_gram", "posterior_draw", "hyper_draw", "sweep_predict", "ring_step")
 GRAM_SCOPE, DRAW_SCOPE, HYPER_SCOPE, PREDICT_SCOPE, RING_SCOPE = SWEEP_SCOPES
+
+#: The tensor model's sequential draw of the time factors, one bin after
+#: another. A scope of its own and not a layer of :data:`SWEEP_SCOPES`: the
+#: draws inside it stay under ``posterior_draw``, and a trace's split by
+#: layer is the same for both models.
+TIME_CHAIN_SCOPE = "time_chain"
 
 
 def gram_tile_rows(P: int) -> int:
@@ -83,13 +93,15 @@ class HyperParams:
 
 @pytree_dataclass
 class BPMFState:
-    """Full Gibbs state."""
+    """Full Gibbs state; ``T`` and ``hyper_T`` only for the tensor model."""
 
     U: jax.Array  # [M, K] user latents
     V: jax.Array  # [N, K] movie latents
     hyper_U: HyperParams
     hyper_V: HyperParams
     sweep: jax.Array  # scalar int32, number of completed sweeps
+    T: jax.Array | None = None  # [K_T, K] time-bin latents (BPTF)
+    hyper_T: HyperParams | None = None  # (mu_T, Lam_T) of the time chain (BPTF)
 
     @property
     def K(self) -> int:
@@ -151,13 +163,15 @@ class Bucket:
 
     All arrays are device arrays; ``item_ids`` indexes the side being updated,
     ``nbr`` indexes the opposite side. Padded neighbor slots have index 0 and
-    ``nnz`` masks them out.
+    ``nnz`` masks them out. In the tensor model a slot has a second
+    neighbour, of the third side, in ``nbr2``.
     """
 
     item_ids: jax.Array  # [B] int32
     nbr: jax.Array  # [B, P] int32, padded neighbor (opposite-side) indices
     val: jax.Array  # [B, P] f32, centered ratings, 0 in padding
     nnz: jax.Array  # [B] int32, true rating count per item
+    nbr2: jax.Array | None = None  # [B, P] int32, second neighbour (BPTF)
 
     @property
     def B(self) -> int:
@@ -193,6 +207,7 @@ class Bucket:
             nbr=tile(self.nbr, 0),
             val=tile(self.val, 0),
             nnz=tile(self.nnz, 0),
+            nbr2=None if self.nbr2 is None else tile(self.nbr2, 0),
         )
 
 
@@ -224,6 +239,7 @@ class TestSet:
     rows: jax.Array  # [T] int32 user ids
     cols: jax.Array  # [T] int32 movie ids
     vals: jax.Array  # [T] f32 raw (uncentered) ratings
+    times: jax.Array | None = None  # [T] int32 time bins (BPTF)
 
 
 @pytree_dataclass
@@ -231,7 +247,8 @@ class BPMFData:
     """Everything the Gibbs sweep needs besides the state.
 
     users / movies are each the bucketed neighbor lists for updating that
-    side. ``mean_rating`` recenters ratings; predictions add it back.
+    side; ``times``, for the tensor model, those of the time bins.
+    ``mean_rating`` recenters ratings; predictions add it back.
     """
 
     users: BucketedSide  # update U: neighbors are movies
@@ -242,6 +259,8 @@ class BPMFData:
     num_movies: int = static_field(default=0)
     min_rating: float = static_field(default=-np.inf)
     max_rating: float = static_field(default=np.inf)
+    times: BucketedSide | None = None  # update T: neighbours are (user, movie)
+    num_times: int = static_field(default=0)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -24,8 +24,10 @@ def _iter_rating_chunks(
     delimiter: str | None,
     skip_header: int,
     chunk_rows: int,
+    with_times: bool = False,
 ):
-    """Yield ``(col0, col1, vals)`` raw-id chunks of a 3+-column rating file.
+    """Yield ``(col0, col1, vals)`` raw-id chunks of a 3+-column rating file
+    (``with_times``: and the fourth column, unix seconds, as int64).
 
     Parsing ``chunk_rows`` lines at a time bounds peak memory by the chunk
     size regardless of file length; chunk boundaries are deterministic
@@ -42,16 +44,18 @@ def _iter_rating_chunks(
             lines = [ln for ln in lines if ln.strip()]
             if not lines:  # chunk of blank lines (e.g. trailing newlines)
                 continue
+            cols = (0, 1, 2, 3) if with_times else (0, 1, 2)
             chunk = np.atleast_2d(
-                np.genfromtxt(lines, delimiter=delimiter, usecols=(0, 1, 2), dtype=np.float64)
+                np.genfromtxt(lines, delimiter=delimiter, usecols=cols, dtype=np.float64)
             )
             if chunk.size == 0:
                 continue
-            yield (
+            out = (
                 chunk[:, 0].astype(np.int64),
                 chunk[:, 1].astype(np.int64),
                 chunk[:, 2].astype(np.float32),
             )
+            yield out + (chunk[:, 3].astype(np.int64),) if with_times else out
 
 
 def _read_rating_chunks(
@@ -60,7 +64,8 @@ def _read_rating_chunks(
     delimiter: str | None,
     skip_header: int,
     chunk_rows: int,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    with_times: bool = False,
+) -> tuple[np.ndarray, ...]:
     """Materialize :func:`_iter_rating_chunks` into full arrays.
 
     The previous one-shot ``np.genfromtxt`` materialized the whole file as an
@@ -69,30 +74,43 @@ def _read_rating_chunks(
     transient by the chunk size, with byte-identical output.
 
     Returns:
-        ``(col0, col1, vals)`` — raw int64 ids and float32 ratings.
+        ``(col0, col1, vals)`` — raw int64 ids and float32 ratings — and
+        with ``with_times`` the int64 timestamps.
     """
-    id0, id1, vals = [], [], []
-    for c0, c1, v in _iter_rating_chunks(
-        path, delimiter=delimiter, skip_header=skip_header, chunk_rows=chunk_rows
+    cols = None
+    for chunk in _iter_rating_chunks(
+        path, delimiter=delimiter, skip_header=skip_header, chunk_rows=chunk_rows,
+        with_times=with_times,
     ):
-        id0.append(c0)
-        id1.append(c1)
-        vals.append(v)
-    if not id0:
+        cols = [[] for _ in chunk] if cols is None else cols
+        for acc, c in zip(cols, chunk):
+            acc.append(c)
+    if cols is None:
         raise ValueError(f"no ratings parsed from {path!r}")
-    return np.concatenate(id0), np.concatenate(id1), np.concatenate(vals)
+    return tuple(np.concatenate(c) for c in cols)
 
 
-def _parse_ratings_csv(path: str, chunk_rows: int = _CSV_CHUNK_ROWS) -> RatingsCOO:
-    """ml-20m ratings.csv: userId,movieId,rating,timestamp (with header)."""
-    users_raw, movies_raw, vals = _read_rating_chunks(
-        path, delimiter=",", skip_header=1, chunk_rows=chunk_rows
+def month_bins(timestamps: np.ndarray) -> tuple[np.ndarray, int]:
+    """Calendar month of each unix timestamp (UTC), counted from the first
+    month present: ``(int32 bins, number of months spanned)``."""
+    months = timestamps.astype("datetime64[s]").astype("datetime64[M]").astype(np.int64)
+    bins = months - months.min()
+    return bins.astype(np.int32), int(bins.max()) + 1
+
+
+def _parse_ratings_csv(path: str, chunk_rows: int = _CSV_CHUNK_ROWS,
+                       times: bool = False) -> RatingsCOO:
+    """ml-20m ratings.csv: userId,movieId,rating,timestamp (with header);
+    ``times`` keeps the timestamp as each rating's calendar month."""
+    users_raw, movies_raw, vals, *stamps = _read_rating_chunks(
+        path, delimiter=",", skip_header=1, chunk_rows=chunk_rows, with_times=times
     )
     _, users = np.unique(users_raw, return_inverse=True)
     _, movies = np.unique(movies_raw, return_inverse=True)
+    bins, num_times = month_bins(stamps[0]) if times else (None, 0)
     return RatingsCOO(
         users.astype(np.int32), movies.astype(np.int32), vals,
-        int(users.max()) + 1, int(movies.max()) + 1,
+        int(users.max()) + 1, int(movies.max()) + 1, bins, num_times,
     )
 
 
@@ -173,11 +191,18 @@ def load_movielens_chunked(
     )
 
 
-def load_movielens(path: str | None = None, variant: str = "ml-100k") -> RatingsCOO:
+def load_movielens(path: str | None = None, variant: str = "ml-100k",
+                   times: bool = False) -> RatingsCOO:
+    """Ratings of a MovieLens file, or a synthetic stand-in; ``times`` keeps
+    each rating's calendar month (``ratings.csv`` only)."""
     if path and os.path.exists(path):
         if path.endswith(".csv"):
-            return _parse_ratings_csv(path)
+            return _parse_ratings_csv(path, times=times)
+        if times:
+            raise ValueError("calendar months are read from an ml-20m ratings.csv only")
         return _parse_udata(path)
+    if times:
+        raise ValueError(f"no MovieLens file at {path!r} to read rating times from")
     logger.info("movielens file not found, generating %s-shaped synthetic data", variant)
     spec = ML20M_LIKE if variant == "ml-20m" else ML100K_LIKE
     coo, _ = synthetic_ratings(spec)

@@ -5,6 +5,10 @@ grouped by rating count into power-of-two padded buckets so that each bucket
 is one dense gather + Gram contraction. Padding waste is bounded by 2x per
 item and is typically ~20-30% on MovieLens/ChEMBL-shaped skew (measured in
 benchmarks/fig2_item_update.py).
+
+Ratings may carry a time bin (``RatingsCOO.times``), which the tensor model
+(BPTF) factorizes as a third side: each of its sides' bucket slots then
+holds two neighbour indices, one of each other side.
 """
 from __future__ import annotations
 
@@ -28,13 +32,32 @@ class RatingsCOO:
     vals: np.ndarray  # [nnz] float32 ratings
     num_users: int
     num_movies: int
+    times: np.ndarray | None = None  # [nnz] int32 time bin of each rating, if any
+    num_times: int = 0
+
+    def __post_init__(self) -> None:
+        t = self.times
+        if t is not None and t.size and (t.min() < 0 or t.max() >= self.num_times):
+            raise ValueError(
+                f"time bins must be in [0, {self.num_times}), got [{t.min()}, {t.max()}]"
+            )
 
     @property
     def nnz(self) -> int:
         return int(self.rows.shape[0])
 
     def transpose(self) -> "RatingsCOO":
-        return RatingsCOO(self.cols, self.rows, self.vals, self.num_movies, self.num_users)
+        return dataclasses.replace(self, rows=self.cols, cols=self.rows,
+                                   num_users=self.num_movies, num_movies=self.num_users)
+
+    def select(self, keep: np.ndarray) -> "RatingsCOO":
+        """The ratings where ``keep`` (a mask or index array) selects."""
+        times = None if self.times is None else self.times[keep]
+        return dataclasses.replace(self, rows=self.rows[keep], cols=self.cols[keep],
+                                   vals=self.vals[keep], times=times)
+
+    def without_times(self) -> "RatingsCOO":
+        return dataclasses.replace(self, times=None, num_times=0)
 
     def chunked(self, chunk_rows: int = 1_000_000) -> "ChunkedRatings":
         """View this in-memory COO as a re-iterable chunk stream (for tests
@@ -143,15 +166,21 @@ def stable_mean(vals: np.ndarray) -> float:
 
 
 def csr_from_coo(
-    rows: np.ndarray, cols: np.ndarray, vals: np.ndarray, num_items: int
-) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """(indptr, indices, values) CSR over ``rows``; columns sorted within rows."""
+    rows: np.ndarray, cols: np.ndarray, vals: np.ndarray, num_items: int,
+    cols2: np.ndarray | None = None,
+) -> tuple[np.ndarray, ...]:
+    """(indptr, indices, values) CSR over ``rows``; columns sorted within rows.
+
+    With ``cols2`` (a second index per rating), its entries in the same
+    order come fourth.
+    """
     order = np.lexsort((cols, rows))
     r, c, v = rows[order], cols[order], vals[order]
     counts = np.bincount(r, minlength=num_items)
     indptr = np.zeros(num_items + 1, dtype=np.int64)
     np.cumsum(counts, out=indptr[1:])
-    return indptr, c.astype(np.int32), v.astype(np.float32)
+    out = (indptr, c.astype(np.int32), v.astype(np.float32))
+    return out if cols2 is None else out + (cols2[order].astype(np.int32),)
 
 
 def _concat_ranges(lengths: np.ndarray) -> np.ndarray:
@@ -170,8 +199,10 @@ def pad_group(
     indices: np.ndarray,
     values: np.ndarray,
     pad: int,
+    indices2: np.ndarray | None = None,
 ) -> Bucket:
-    """Densify the CSR rows ``ids`` into a [B, pad] padded bucket."""
+    """Densify the CSR rows ``ids`` into a [B, pad] padded bucket (with
+    ``indices2``, a second neighbour index per slot in ``nbr2``)."""
     ids = np.asarray(ids, dtype=np.int64)
     B = len(ids)
     nnz = (indptr[ids + 1] - indptr[ids]).astype(np.int64)
@@ -184,11 +215,17 @@ def pad_group(
     src = np.repeat(indptr[ids], nnz) + within
     nbr.reshape(-1)[flat_dst] = indices[src]
     val.reshape(-1)[flat_dst] = values[src]
+    nbr2 = None
+    if indices2 is not None:
+        nbr2 = np.zeros((B, pad), dtype=np.int32)
+        nbr2.reshape(-1)[flat_dst] = indices2[src]
+        nbr2 = jnp.asarray(nbr2)
     return Bucket(
         item_ids=jnp.asarray(ids, jnp.int32),
         nbr=jnp.asarray(nbr),
         val=jnp.asarray(val),
         nnz=jnp.asarray(nnz, jnp.int32),
+        nbr2=nbr2,
     )
 
 
@@ -217,11 +254,13 @@ def bucketize_side(
     pads: Sequence[int],
     *,
     include_empty: bool = True,
+    indices2: np.ndarray | None = None,
 ) -> BucketedSide:
     """Bucket every CSR row (item) by nnz into padded dense groups.
 
     Items with zero ratings still get sampled (from the prior conditional),
-    so they are included in the smallest bucket by default.
+    so they are included in the smallest bucket by default. ``indices2``
+    gives each rating a second neighbour (:func:`pad_group`).
     """
     num_items = len(indptr) - 1
     nnz = (indptr[1:] - indptr[:-1]).astype(np.int64)
@@ -232,7 +271,7 @@ def bucketize_side(
     buckets = []
     for pad in sorted(assign):
         ids = items[assign[pad]]
-        buckets.append(pad_group(ids, indptr, indices, values, pad))
+        buckets.append(pad_group(ids, indptr, indices, values, pad, indices2))
     return BucketedSide(buckets=tuple(buckets), num_items=num_items)
 
 
@@ -241,11 +280,7 @@ def train_test_split(
 ) -> tuple[RatingsCOO, RatingsCOO]:
     rng = np.random.default_rng(seed)
     t = rng.random(coo.nnz) < test_fraction
-    tr = ~t
-    return (
-        RatingsCOO(coo.rows[tr], coo.cols[tr], coo.vals[tr], coo.num_users, coo.num_movies),
-        RatingsCOO(coo.rows[t], coo.cols[t], coo.vals[t], coo.num_users, coo.num_movies),
-    )
+    return coo.select(~t), coo.select(t)
 
 
 def build_bpmf_data(
@@ -256,7 +291,8 @@ def build_bpmf_data(
     min_rating: float | None = None,
     max_rating: float | None = None,
 ) -> BPMFData:
-    """Full host-side pipeline: split, center, bucket both sides."""
+    """Full host-side pipeline: split, center, bucket both sides (and the
+    time side, when the ratings carry times)."""
     train, test = train_test_split(coo, test_fraction, seed)
     lo = float(coo.vals.min()) if min_rating is None else min_rating
     hi = float(coo.vals.max()) if max_rating is None else max_rating
@@ -287,8 +323,23 @@ def build_bpmf_data_presplit(
     )
     centered = train.vals - mean
 
-    u_indptr, u_idx, u_val = csr_from_coo(train.rows, train.cols, centered, train.num_users)
-    m_indptr, m_idx, m_val = csr_from_coo(train.cols, train.rows, centered, train.num_movies)
+    times, test_times = None, None
+    if train.times is None:
+        u_indptr, u_idx, u_val = csr_from_coo(train.rows, train.cols, centered, train.num_users)
+        m_indptr, m_idx, m_val = csr_from_coo(train.cols, train.rows, centered, train.num_movies)
+        users = bucketize_side(u_indptr, u_idx, u_val, pads)
+        movies = bucketize_side(m_indptr, m_idx, m_val, pads)
+    else:
+        # each side's neighbours are the two other sides: users (movie,
+        # time), movies (user, time), time bins (user, movie)
+        r, c, t = train.rows, train.cols, train.times
+        sides = []
+        for items, nbrs, nbrs2, n in ((r, c, t, train.num_users), (c, r, t, train.num_movies),
+                                      (t, r, c, train.num_times)):
+            indptr, idx, val, idx2 = csr_from_coo(items, nbrs, centered, n, nbrs2)
+            sides.append(bucketize_side(indptr, idx, val, pads, indices2=idx2))
+        users, movies, times = sides
+        test_times = jnp.asarray(test.times, jnp.int32)
 
     all_vals = np.concatenate([train.vals, test.vals]) if train.nnz or test.nnz else None
     lo = (float(all_vals.min()) if all_vals is not None else -np.inf) \
@@ -296,16 +347,19 @@ def build_bpmf_data_presplit(
     hi = (float(all_vals.max()) if all_vals is not None else np.inf) \
         if max_rating is None else max_rating
     return BPMFData(
-        users=bucketize_side(u_indptr, u_idx, u_val, pads),
-        movies=bucketize_side(m_indptr, m_idx, m_val, pads),
+        users=users,
+        movies=movies,
         test=TestSet(
             rows=jnp.asarray(test.rows, jnp.int32),
             cols=jnp.asarray(test.cols, jnp.int32),
             vals=jnp.asarray(test.vals, jnp.float32),
+            times=test_times,
         ),
         mean_rating=jnp.asarray(mean, jnp.float32),
         num_users=train.num_users,
         num_movies=train.num_movies,
         min_rating=lo,
         max_rating=hi,
+        times=times,
+        num_times=train.num_times if times is not None else 0,
     )

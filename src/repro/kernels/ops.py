@@ -106,7 +106,8 @@ def _pad_axis(x: jax.Array, axis: int, multiple: int, fill=0) -> jax.Array:
 
 
 def _bpmf_gram_xla(
-    X: jax.Array, nbr: jax.Array, val: jax.Array, nnz: jax.Array, compute_dtype
+    X: jax.Array, nbr: jax.Array, val: jax.Array, nnz: jax.Array, compute_dtype,
+    X2: jax.Array | None = None, nbr2: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """The production XLA path: gather once, one augmented contraction.
 
@@ -117,10 +118,17 @@ def _bpmf_gram_xla(
     the naive two-einsum oracle)::
 
         Z = Y^T Y,  Y = [Xn, val]   →   G = Z[:K, :K],  g = Z[:K, K]
+
+    With a second factor matrix and index per slot (the tensor model), the
+    neighbour vector is the Hadamard product of two gathered rows,
+    ``Xn = X[nbr] * X2[nbr2]``, and the contraction is the same.
     """
     P = nbr.shape[1]
     mask = (jnp.arange(P, dtype=jnp.int32)[None, :] < nnz[:, None]).astype(compute_dtype)
-    Xn = jnp.take(X, nbr, axis=0).astype(compute_dtype) * mask[..., None]
+    Xn = jnp.take(X, nbr, axis=0).astype(compute_dtype)
+    if X2 is not None:
+        Xn = Xn * jnp.take(X2, nbr2, axis=0).astype(compute_dtype)
+    Xn = Xn * mask[..., None]
     Y = jnp.concatenate([Xn, val.astype(compute_dtype)[..., None]], axis=-1)
     Z = jnp.einsum(
         "bpi,bpj->bij", Y, Y,
@@ -178,6 +186,8 @@ def bpmf_gram(
     ns_chunk: int | None = None,
     force_pallas: bool | None = None,
     interpret: bool | None = None,
+    X2: jax.Array | None = None,
+    nbr2: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Dispatch the per-bucket gather+Gram op; returns (G [B,K,K], g [B,K]).
 
@@ -186,6 +196,10 @@ def bpmf_gram(
     tiling. ``force_pallas`` is the legacy boolean override (maps to
     ``impl``). When the shard exceeds the VMEM budget the kernel streams it
     in ``ns_chunk`` rows; when even that cannot fit, a Pallas impl raises.
+
+    ``X2`` and ``nbr2`` give each slot a second neighbour whose row
+    multiplies the first (:func:`_bpmf_gram_xla`); such a three-way bucket
+    runs on XLA (``auto``), and the two-way Pallas kernels refuse it.
     """
     B, P = nbr.shape
     Ns, K = X.shape
@@ -193,6 +207,11 @@ def bpmf_gram(
         interpret = pallas_interpret()
     if force_pallas is not None:
         impl = "pallas" if force_pallas else "xla"
+    if nbr2 is not None and impl != "xla":
+        if impl != "auto":
+            raise ValueError(f"gram impl {impl!r} is two-way only; a three-way bucket "
+                             "runs on 'xla' or 'auto'")
+        impl = "xla"
     if impl == "auto":
         dec = autotune.decide(autotune.bucket_key(B, P, Ns, K, compute_dtype))
     elif impl in ("pallas", "pallas_fused"):  # fused degenerates to per-bucket here
@@ -210,7 +229,7 @@ def bpmf_gram(
         )
     _record("bucket", (B, P, Ns, K), dec)
     if dec.impl == "xla":
-        return _bpmf_gram_xla(X, nbr, val, nnz, compute_dtype)
+        return _bpmf_gram_xla(X, nbr, val, nnz, compute_dtype, X2, nbr2)
     nbr_p = _pad_axis(_pad_axis(nbr, 1, dec.pc), 0, dec.tb)
     val_p = _pad_axis(_pad_axis(val, 1, dec.pc), 0, dec.tb)
     nnz_p = _pad_axis(nnz, 0, dec.tb)

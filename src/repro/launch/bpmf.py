@@ -4,7 +4,10 @@
         --backend ring --dataset synthetic --sweeps 50 \
         --devices 8 --checkpoint-dir /tmp/bpmf-ckpt
 
-Prints per-sweep sample and posterior-mean RMSE. ``--resume`` continues
+Prints per-sweep sample and posterior-mean RMSE. ``--model bptf`` fits the
+temporal tensor model (user x movie x time bin) on the ``sequential``
+backend: synthetic ratings get ``--times`` uniform bins, MovieLens
+``ratings.csv`` its calendar months. ``--resume`` continues
 from the latest checkpoint in ``--checkpoint-dir`` with randomness
 identical to an uninterrupted run. ``--devices N`` forces N host devices
 (CPU) so the ring/allgather backends exercise a real multi-device mesh —
@@ -41,6 +44,11 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--users", type=int, default=400, help="synthetic: number of users")
     p.add_argument("--movies", type=int, default=300, help="synthetic: number of movies")
     p.add_argument("--nnz", type=int, default=12_000, help="synthetic: number of ratings")
+    p.add_argument("--times", type=int, default=12,
+                   help="synthetic with --model bptf: number of time bins")
+    p.add_argument("--model", default="bpmf", choices=["bpmf", "bptf"],
+                   help="bpmf (user x movie) or bptf (user x movie x time bin; "
+                        "sequential backend, ratings with times)")
     p.add_argument("--K", type=int, default=16, help="latent rank")
     p.add_argument("--alpha", type=float, default=2.0, help="rating noise precision")
     p.add_argument("--sweeps", type=int, default=50)
@@ -129,10 +137,14 @@ def main(argv: list[str] | None = None) -> int:
     say = print if main_proc else (lambda *a, **kw: None)
 
     dataset_kw = {}
+    tensor = args.model == "bptf"
     if args.dataset == "synthetic":
-        dataset_kw = dict(num_users=args.users, num_movies=args.movies, nnz=args.nnz)
+        dataset_kw = dict(num_users=args.users, num_movies=args.movies, nnz=args.nnz,
+                          num_times=args.times if tensor else 0)
     elif args.dataset_path:
         dataset_kw = dict(path=args.dataset_path)
+    if tensor and args.dataset == "movielens":
+        dataset_kw["times"] = True
     coo = load_dataset(args.dataset, **dataset_kw)
 
     # pass both through: BackendConfig warns on the deprecated flag alone
@@ -147,6 +159,7 @@ def main(argv: list[str] | None = None) -> int:
         num_partitions=args.num_partitions,
         merge_method=args.merge_method,
         **gram_kw,
+        family=args.model,
         K=args.K,
         alpha=args.alpha,
         num_sweeps=args.sweeps,
@@ -177,7 +190,8 @@ def main(argv: list[str] | None = None) -> int:
     say(
         f"backend={args.backend} devices={len(jax.devices())} "
         f"processes={jax.process_count()} "
-        f"dataset={args.dataset} R: {coo.num_users} x {coo.num_movies}, "
+        f"model={args.model} dataset={args.dataset} R: {coo.num_users} x {coo.num_movies}"
+        f"{f' x {coo.num_times}' if tensor else ''}, "
         f"{coo.nnz} ratings; K={cfg.model.K} sweeps={cfg.run.num_sweeps}"
     )
     t0 = time.time()

@@ -206,3 +206,30 @@ def test_sequential_block_fits_one_chip_at_chembl(one_chip, monkeypatch):
     need = compiled_hbm_bytes(compiled)
     assert need + HEADROOM <= V5E_HBM, f"{need / 2**30:.2f} GiB of {V5E_HBM / 2**30} GiB"
     assert np.isfinite(need) and need > 0
+
+
+def test_bptf_block_compiles_with_the_chain_on_the_kernel(one_chip, monkeypatch):
+    """The tensor model's block compiles for the v5e, every draw on the
+    lane-batched kernel: the users', movies' and time bins' tiles, and the
+    time chain's one-bin draw inside its scan, under ``time_chain``."""
+    from repro.bpmf import load_dataset
+    from repro.data.sparse import build_bpmf_data
+
+    M, N, KT = 500, 200, 12
+    coo = load_dataset("synthetic", num_users=M, num_movies=N, nnz=5000, num_times=KT)
+    data = build_bpmf_data(coo)
+    cfg = BPMFConfig(K=K, gram_impl="xla")
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    state = jax.eval_shape(lambda k: gibbs.init_state(k, M, N, cfg, KT), key)
+    pred = jax.eval_shape(lambda: PredictionState.init(int(data.test.rows.shape[0])))
+    accum = jax.eval_shape(lambda: PosteriorAccum.init(M, N, K, 8))
+    args = [_abstract(a, one_chip) for a in (key, state, pred, accum, data)]
+    monkeypatch.setattr(ops, "draw_decision", functools.partial(ops.draw_decision, backend="tpu"))
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    with ops.record_draw_decisions() as draws:
+        lowered = gibbs.gibbs_sweep_block_donated.lower(*args, cfg, 2)
+    assert {dec.impl for _, _, dec in draws} == {"pallas"}
+    assert (1, K) in [shape for _, shape, _ in draws]
+    kernels = [line for line in lowered.compile().as_text().splitlines()
+               if (m := CUSTOM_CALL.search(line)) and m.group(2) == "tpu_custom_call"]
+    assert any("/time_chain/" in line for line in kernels)

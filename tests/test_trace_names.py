@@ -67,6 +67,28 @@ def test_sequential_block_names_its_layers():
         assert None not in by_prim[prim], prim
 
 
+def test_bptf_block_names_the_chain_and_the_gram_of_three_sides():
+    """The tensor model's block: its time chain runs under ``time_chain``,
+    with its draws under ``posterior_draw`` inside it, and the Gram of
+    each of the three sides (gathers of U, V and T rows) under
+    ``bpmf_gram``; ``time_chain`` is not one of the sweep's layers, so a
+    trace's split by layer stays as it was."""
+    coo = load_dataset("synthetic", num_users=60, num_movies=40, nnz=600, num_times=6)
+    cfg = BPMFConfig().replace(family="bptf", K=4, num_sweeps=4, sweeps_per_block=2)
+    hlo = BPMFEngine(cfg).prepare(coo).lower_block().compile().as_text()
+    chain = [p for p in OP_NAME.findall(hlo) if "time_chain" in p.split("/")]
+    assert chain and {innermost(p) for p in chain} >= {"posterior_draw"}
+    # a gather's operand is a parameter of its fused computation, whose
+    # shape the computation's header states
+    shapes = dict(re.findall(r"(param_[\d.]+): (f32\[[\d,]+\])", hlo))
+    gathered = {shapes.get(m.group(1).lstrip("%")) for line in hlo.splitlines()
+                if "/bpmf_gram/" in line and (m := re.search(r" gather\((%?[\w.]+),", line))}
+    assert {"f32[60,4]", "f32[40,4]", "f32[6,4]"} <= gathered, gathered
+    assert SWEEP_SCOPES == ("bpmf_gram", "posterior_draw", "hyper_draw", "sweep_predict",
+                            "ring_step")
+    assert types.TIME_CHAIN_SCOPE not in SWEEP_SCOPES
+
+
 def pallas_call_paths(jaxpr) -> list[str]:
     """The full name-stack path of every ``pallas_call`` in a closed jaxpr,
     as it becomes the kernel custom-call's ``op_name``."""
